@@ -295,8 +295,9 @@ def bench_compression(*, write_json: bool = True) -> Dict[str, Dict]:
     (b) the fused wire encode leg — encode_sparse packs the frame body in
         ONE device buffer / ONE host transfer — plus decode and the dense
         decompress;
-    (c) launch counts of the blocked Pallas pipeline (stats + exact-k emit
-        + pack), gated by ``run.py --check`` like the other suites.
+    (c) launch counts of the blocked Pallas pipeline (stats + exact-k
+        emit) and of the wire pack (XLA, none), gated by ``run.py
+        --check`` like the other suites.
 
     Writes results/BENCH_compression.json.
     """
@@ -345,13 +346,9 @@ def bench_compression(*, write_json: bool = True) -> Dict[str, Dict]:
     VK.reset_launch_count()
     K.blocked_topk_sparsify(small, int(C._MIN_FAST_N * density))
     launches_topk = VK.launch_count()
+    # the wire pack is XLA (kernels/ref.py::pack_body): no Pallas launch
     VK.reset_launch_count()
-    K.fused_quantize_pack(payload.values.astype(jnp.float32)[:4096],
-                          payload.indices[:4096])
-    launches_qpack = VK.launch_count()
-    VK.reset_launch_count()
-    K.fused_pack_body(payload.values[:4096], payload.scales[:16],
-                      payload.indices[:4096])
+    wire.encode_sparse(payload)
     launches_pack = VK.launch_count()
 
     # end-to-end compressed assimilation on the SAME 24-leaf/4-island model
@@ -394,10 +391,8 @@ def bench_compression(*, write_json: bool = True) -> Dict[str, Dict]:
                        f" vs pre-blocked-topk {_PRE_BLOCKED_TOPK_US:.0f}us"},
         "pallas_launches": {"us_per_call": 0.0,
                             "derived": f"blocked_topk={launches_topk} "
-                                       f"quantize_pack={launches_qpack} "
                                        f"pack_body={launches_pack}"},
         "_launches": {"blocked_topk": launches_topk,
-                      "quantize_pack": launches_qpack,
                       "pack_body": launches_pack},
     }
     if write_json:
@@ -409,7 +404,7 @@ def bench_compression(*, write_json: bool = True) -> Dict[str, Dict]:
 
 
 def _bench_sharded_flat_impl(n_shards: int) -> Dict[str, Dict]:
-    """Runs inside a process whose host platform has >= n_shards devices."""
+    """Runs inside a process that has >= n_shards devices."""
     from repro.core import flat as F
     from repro.core import vc_asgd as V
     from repro.kernels import vc_asgd_update as VK
@@ -470,7 +465,10 @@ def _bench_sharded_flat_impl(n_shards: int) -> Dict[str, Dict]:
         "model": {"us_per_call": 0.0,
                   "derived": f"{n_leaves} leaves / {n_shards} shards x "
                              f"{fp.spec.shard_len} elems / "
-                             f"{jax.local_device_count()} devices"},
+                             f"{jax.local_device_count()} "
+                             f"{jax.default_backend()} devices",
+                  "backend": jax.default_backend(),
+                  "device_kind": jax.devices()[0].device_kind},
         "flatten_single": {"us_per_call": round(us_flat_single, 1),
                            "derived": f"padded={F.flatten(tree).spec.padded}"},
         "flatten_sharded": {"us_per_call": round(us_flat_shard, 1),
@@ -492,19 +490,23 @@ def bench_sharded_flat(*, n_shards: int = 4, write_json: bool = True
                        ) -> Dict[str, Dict]:
     """ShardedFlat: the partitioned bus (core/flat.py ShardedTreeSpec +
     runtime/sharding.py shard_map ops) against the single-host flat path —
-    flatten/assimilate wall-clock and pallas launch counts on the CPU pod
-    mesh.  The main process keeps one device (dry-run rules), so the
-    measurement re-execs itself with xla_force_host_platform_device_count
-    when needed.  Writes results/BENCH_sharded_flat.json."""
-    if jax.local_device_count() >= n_shards:
+    flatten/assimilate wall-clock and pallas launch counts on a pod mesh
+    of ``n_shards`` devices.  With that many devices it measures on them
+    in this process; otherwise on a CPU mesh of ``n_shards`` virtual
+    devices in a ``JAX_PLATFORMS=cpu`` child, which needs no device this
+    process holds.  The "model" row names the backend and device kind.
+    Writes results/BENCH_sharded_flat.json."""
+    if jax.device_count() >= n_shards:
         out = _bench_sharded_flat_impl(n_shards)
     else:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + f" --xla_force_host_platform_device_count="
                               f"{n_shards}").strip()
         src = Path(__file__).resolve().parents[1] / "src"
-        env["PYTHONPATH"] = f"{src}{os.pathsep}" + env.get("PYTHONPATH", "")
+        env["PYTHONPATH"] = (f"{src}{os.pathsep}"
+                             + env.get("PYTHONPATH", ""))
         proc = subprocess.run(
             [sys.executable, "-m", "benchmarks.kernel_bench",
              "--emit-sharded-flat", str(n_shards)],
@@ -523,7 +525,7 @@ def bench_sharded_flat(*, n_shards: int = 4, write_json: bool = True
 
 
 if __name__ == "__main__":
-    # subprocess entry for bench_sharded_flat's multi-device re-exec
+    # subprocess entry for bench_sharded_flat's CPU pod mesh
     if len(sys.argv) >= 3 and sys.argv[1] == "--emit-sharded-flat":
         print(json.dumps(_bench_sharded_flat_impl(int(sys.argv[2]))))
     else:

@@ -182,6 +182,8 @@ def main(argv=None) -> None:
                     help="rewrite results/BASELINE_launches.json (and the "
                          "vclint baseline) from a fresh run")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
 

@@ -1,38 +1,28 @@
 """End-to-end driver: train a ~100M-parameter LM for a few hundred steps
 with the pod-scale VC-ASGD runtime (islands + Eq. 2 assimilation +
-checkpoint/restart + a mid-run simulated island preemption).
+checkpoint/restart + a mid-run simulated island preemption), through
+``launch/train.py::train``.
 
-This is the deliverable-(b) end-to-end example. On this CPU container it
-runs a genuinely ~100M-param model — expect ~1-2s/round after compile with
-the default flags; shrink --d-model for a faster demo.
+It runs on the devices it finds: one island per device pair when there
+are several (two islands at most), one island on a single chip.  On a CPU
+host, give it virtual devices; shrink --d-model for a faster demo.
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
   PYTHONPATH=src python examples/vc_train_llm.py --rounds 60
 """
 import argparse
-import os
 import sys
-import time
+import tempfile
 from pathlib import Path
-
-if "--xla-devices" in sys.argv or "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax                          # noqa: E402
-import jax.numpy as jnp             # noqa: E402
-import numpy as np                  # noqa: E402
 
-from repro.checkpoint import CheckpointManager          # noqa: E402
-from repro.core.vc_asgd import var_alpha                # noqa: E402
-from repro.data import make_batch_for                   # noqa: E402
+from repro import compile_cache                         # noqa: E402
+from repro.launch.train import train                    # noqa: E402
 from repro.models.common import BlockSpec, ModelConfig, uniform_groups  # noqa: E402
-from repro.models.registry import build_model           # noqa: E402
-from repro.optim import Adam, cosine_schedule           # noqa: E402
-from repro.runtime.sharding import MeshPlan             # noqa: E402
-from repro.runtime.vc_runtime import make_vc_round      # noqa: E402
+from repro.optim import cosine_schedule                 # noqa: E402
 
 
 def hundred_m_config(d_model: int) -> ModelConfig:
@@ -46,69 +36,38 @@ def hundred_m_config(d_model: int) -> ModelConfig:
         attn_q_block=256, attn_kv_block=256)
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=60)
     ap.add_argument("--local-steps", type=int, default=2)
-    ap.add_argument("--islands", type=int, default=2)
+    ap.add_argument("--islands", type=int, default=None,
+                    help="default: 2 when there are 2+ devices, else 1")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--d-model", type=int, default=640)
     ap.add_argument("--preempt-round", type=int, default=25)
-    ap.add_argument("--ckpt", default="/tmp/vc_llm_ckpt")
-    args = ap.parse_args()
+    ap.add_argument("--ckpt", default=None,
+                    help="checkpoint directory (default: a fresh one)")
+    args = ap.parse_args(argv)
 
-    cfg = hundred_m_config(args.d_model)
-    model = build_model(cfg)
-    n_params = sum(x.size for x in jax.tree.leaves(model.param_specs()))
-    print(f"[llm] {cfg.describe()}  ({n_params / 1e6:.1f}M params)")
-
+    compile_cache.enable()
     n_dev = len(jax.devices())
-    tp = 2 if n_dev >= 4 else 1
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((args.islands, max(1, n_dev // (args.islands * tp)),
-                             tp), ("pod", "data", "model"))
-    plan = MeshPlan.build(cfg, mesh)
-    opt = Adam(lr=cosine_schedule(3e-4, warmup=20,
-                                  total=args.rounds * args.local_steps))
-    vc_round = jax.jit(make_vc_round(model, plan, args.islands,
-                                     args.local_steps, opt))
-    alpha_fn = var_alpha()
-    ckpt = CheckpointManager(args.ckpt, keep=2)
-    key = jax.random.PRNGKey(0)
-
-    with mesh:
-        server = model.init(key)
-        islands = jax.tree.map(
-            lambda s: jnp.broadcast_to(s[None], (args.islands, *s.shape)),
-            server)
-        opts = jax.vmap(opt.init)(islands)
-        t_start = time.time()
-        for rnd in range(args.rounds):
-            bs = []
-            for p in range(args.islands):
-                steps = [make_batch_for(cfg, args.batch, args.seq,
-                                        seed=rnd * 97 + p * 13 + s)
-                         for s in range(args.local_steps)]
-                bs.append(jax.tree.map(lambda *x: jnp.stack(x), *steps))
-            batches = jax.tree.map(lambda *x: jnp.stack(x), *bs)
-            surv = np.ones((args.islands,), bool)
-            if rnd == args.preempt_round:
-                surv[0] = False
-                print(f"[llm] round {rnd}: island 0 preempted -> masked")
-            server, islands, opts, m = vc_round(
-                server, islands, opts, batches,
-                jnp.asarray(alpha_fn(rnd + 1), jnp.float32),
-                jnp.asarray(surv))
-            if rnd % 5 == 0 or rnd == args.rounds - 1:
-                print(f"[llm] round {rnd:3d} loss={float(m['loss']):.4f} "
-                      f"({time.time() - t_start:.0f}s)")
-            if rnd % 20 == 19:
-                ckpt.save(rnd + 1, server, {"round": rnd + 1})
-        ckpt.wait()
-    print(f"[llm] done in {time.time() - t_start:.0f}s; "
-          f"server checkpoints in {args.ckpt}")
+    islands = args.islands or min(2, n_dev)
+    per_island = n_dev // islands
+    tp = 2 if per_island >= 2 else 1
+    cfg = hundred_m_config(args.d_model)
+    ckpt = args.ckpt or tempfile.mkdtemp(prefix="vc_llm_ckpt_")
+    lr = cosine_schedule(3e-4, warmup=20,
+                         total=args.rounds * args.local_steps)
+    out = train(cfg, ckpt_dir=ckpt, islands=islands, rounds=args.rounds,
+                local_steps=args.local_steps, batch=args.batch,
+                seq=args.seq, lr=lr, mesh_shape=(per_island // tp, tp),
+                preempt_round=args.preempt_round, ckpt_every=20,
+                ckpt_keep=2)
+    print(f"[llm] done: loss {out['losses'][0]:.4f} -> "
+          f"{out['losses'][-1]:.4f}; server checkpoints in {ckpt}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -39,12 +39,6 @@ TRIANGLE: Dict[str, dict] = {
     "dequantize_int8": {
         "module": "quantize", "oracles": ["dequantize_int8"],
         "tests": ["test_quantize_roundtrip"]},
-    "pack_body": {
-        "module": "sparse_pack", "oracles": ["pack_body"],
-        "tests": ["test_fused_encode_byte_identity_with_pre_pr_layout"]},
-    "quantize_pack": {
-        "module": "sparse_pack", "oracles": ["quantize_pack"],
-        "tests": ["test_fused_quantize_pack_self_consistent"]},
     "threshold_sparsify": {
         "module": "topk_mask", "oracles": ["threshold_sparsify"],
         "tests": ["test_threshold_sparsify"]},

@@ -15,6 +15,23 @@ def config() -> ModelConfig:
     )
 
 
+# One TPU v5e chip's share for training at the published widths.  Every
+# width is as published; two cuts make params + Adam state + activations
+# fit the chip's 16 GB:
+#   depth  24 -> 2 layers: the other 22 would sit on further chips as
+#          pipeline stages;
+#   vocab  92544 -> 11568: this chip's share of a vocabulary split over
+#          VOCAB_CHIPS chips; tokens, logits and loss are over the slice.
+CHIP_LAYERS = 2
+VOCAB_CHIPS = 8
+
+
+def chip_share() -> ModelConfig:
+    c = config()
+    return c.replace(layer_groups=uniform_groups(CHIP_LAYERS, BlockSpec()),
+                     vocab_size=c.vocab_size // VOCAB_CHIPS)
+
+
 def reduced() -> ModelConfig:
     return config().replace(
         d_model=64, n_heads=4, n_kv_heads=2, d_ff=160, vocab_size=256,

@@ -37,14 +37,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block: int, causal: bool,
 
     def body(j, carry):
         m, l, acc = carry
-        # scalar positions must be pl.dslice(0, 1), not bare Python ints —
-        # the state-discharge rule only accepts Slice/array indices
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.ds(j * kv_block, kv_block),
-                            slice(None)))[0, 0].astype(jnp.float32)  # [kb, hd]
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.ds(j * kv_block, kv_block),
-                            slice(None)))[0, 0].astype(jnp.float32)
+        kv_rows = pl.ds(j * kv_block, kv_block)
+        k = k_ref[0, 0, kv_rows, :].astype(jnp.float32)     # [kb, hd]
+        v = v_ref[0, 0, kv_rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:

@@ -23,22 +23,16 @@ def _mamba_kernel(u_ref, dt_ref, B_ref, C_ref, A_ref, D_ref, o_ref, *,
     D = D_ref[...].reshape(-1).astype(jnp.float32)         # [dblk]
     dblk, ds = A.shape
 
-    # NOTE: scalar positions must be pl.dslice(0, 1), not bare Python ints —
-    # the state-discharge rule only accepts Slice/array indices.
+    def row(ref, t):
+        return ref[0, pl.ds(t, 1), :][0].astype(jnp.float32)
+
     def step(t, h):
-        u = pl.load(u_ref, (pl.dslice(0, 1), pl.ds(t, 1), slice(None)))[0, 0] \
-            .astype(jnp.float32)                           # [dblk]
-        dt = pl.load(dt_ref, (pl.dslice(0, 1), pl.ds(t, 1), slice(None)))[0, 0] \
-            .astype(jnp.float32)
-        B = pl.load(B_ref, (pl.dslice(0, 1), pl.ds(t, 1), slice(None)))[0, 0] \
-            .astype(jnp.float32)                           # [ds]
-        C = pl.load(C_ref, (pl.dslice(0, 1), pl.ds(t, 1), slice(None)))[0, 0] \
-            .astype(jnp.float32)
+        u, dt = row(u_ref, t), row(dt_ref, t)              # [dblk]
+        B, C = row(B_ref, t), row(C_ref, t)                # [ds]
         a_bar = jnp.exp(dt[:, None] * A)                   # [dblk, ds]
         h = a_bar * h + (dt * u)[:, None] * B[None, :]
         y = (h * C[None, :]).sum(axis=1) + D * u
-        pl.store(o_ref, (pl.dslice(0, 1), pl.ds(t, 1), slice(None)),
-                 y[None, None, :].astype(o_ref.dtype))
+        o_ref[0, pl.ds(t, 1), :] = y[None, :].astype(o_ref.dtype)
         return h
 
     jax.lax.fori_loop(0, seq, step, jnp.zeros((dblk, ds), jnp.float32))

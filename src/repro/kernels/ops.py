@@ -1,9 +1,10 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On CPU (this container) every kernel runs in interpret mode — the kernel
-body executes as traced jnp ops, which validates BlockSpecs, index maps and
-the kernel math against ref.py.  On TPU backends the same calls compile to
-Mosaic.  ``interpret`` is decided once per process from the backend.
+Off the TPU every kernel runs in interpret mode — the kernel body
+executes as traced jnp ops, which validates BlockSpecs, index maps and the
+kernel math against ref.py; that is the CPU test route and nothing else.
+On a TPU backend every call compiles to Mosaic and none is interpreted
+(``chip_smoke.py`` asserts it).
 """
 from __future__ import annotations
 
@@ -16,12 +17,12 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import mamba_scan as _ms
 from repro.kernels import quantize as _qz
 from repro.kernels import rwkv6_scan as _rw
-from repro.kernels import sparse_pack as _sp
 from repro.kernels import topk_mask as _tm
 from repro.kernels import vc_asgd_update as _vc
 
 
 def _interpret() -> bool:
+    """True off the TPU (the CPU test route); False on a TPU backend."""
     return jax.default_backend() != "tpu"
 
 
@@ -112,13 +113,3 @@ def blocked_topk_sparsify(x, k):
     + exact-k emit launch; dense fallback when the bracket misses."""
     return _tm.blocked_topk_sparsify(x, k, interpret=_interpret())
 
-
-def fused_quantize_pack(sel, idx, block=256):
-    """Quantize + pack the sparse wire-frame body in ONE launch."""
-    return _sp.quantize_pack(sel, idx, block=block, interpret=_interpret())
-
-
-def fused_pack_body(q, scales, idx):
-    """Pack an existing payload into the wire body — bitcast-only, so the
-    bytes equal the payload arrays' own bytes exactly."""
-    return _sp.pack_body(q, scales, idx, interpret=_interpret())

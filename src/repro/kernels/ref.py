@@ -163,7 +163,9 @@ def threshold_sparsify_exact(x, tau, tie_start, tie_budget,
 
 def pack_body(q, scales, idx):
     """Sparse wire-frame body bytes: values(int8) || scales(f32) ||
-    indices(int32), the layout transfer/wire.py pins (little-endian)."""
+    indices(int32), the layout transfer/wire.py pins (little-endian).
+    The wire's encode leg runs this jitted: the pack is bitcasts and one
+    concat, which XLA does in one pass on any backend."""
     qb = jax.lax.bitcast_convert_type(q.astype(jnp.int8), jnp.uint8)
     sb = jax.lax.bitcast_convert_type(scales.astype(jnp.float32),
                                       jnp.uint8).reshape(-1)
@@ -171,12 +173,3 @@ def pack_body(q, scales, idx):
                                       jnp.uint8).reshape(-1)
     return jnp.concatenate([qb, sb, ib])
 
-
-def quantize_pack(sel, idx, block: int = 256):
-    """Fused quantize+pack oracle (kernels/sparse_pack.py): returns
-    (body uint8, q int8 padded to ng*block, scales f32 [ng])."""
-    k = sel.size
-    ng = -(-k // block)
-    q, scales = quantize_int8(sel, block)
-    qpad = jnp.pad(q, (0, ng * block - k))
-    return pack_body(q, scales, idx), qpad, scales

@@ -11,32 +11,26 @@ and the per-step decay arrive as whole-sequence VMEM tiles.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, *, seq: int):
-    u = u_ref[0].astype(jnp.float32)                         # [hd]
+    u = u_ref[0, 0].astype(jnp.float32)                      # [hd]
     hd = u.shape[0]
 
-    # NOTE: scalar positions must be pl.dslice(0, 1), not bare Python ints —
-    # the state-discharge rule only accepts Slice/array indices.
-    _01 = (pl.dslice(0, 1), pl.dslice(0, 1))
+    def row(ref, t):
+        return ref[0, 0, pl.ds(t, 1), :][0].astype(jnp.float32)   # [hd]
 
     def step(t, S):
-        r = pl.load(r_ref, _01 + (pl.ds(t, 1), slice(None)))[0, 0, 0] \
-            .astype(jnp.float32)                             # [hd]
-        k = pl.load(k_ref, _01 + (pl.ds(t, 1), slice(None)))[0, 0, 0] \
-            .astype(jnp.float32)
-        v = pl.load(v_ref, _01 + (pl.ds(t, 1), slice(None)))[0, 0, 0] \
-            .astype(jnp.float32)
-        w = pl.load(w_ref, _01 + (pl.ds(t, 1), slice(None)))[0, 0, 0] \
-            .astype(jnp.float32)                             # decay in (0,1)
+        r, k, v = row(r_ref, t), row(k_ref, t), row(v_ref, t)
+        w = row(w_ref, t)                                    # decay in (0,1)
         kv = k[:, None] * v[None, :]                         # [hd, hd]
         out = ((S + u[:, None] * kv) * r[:, None]).sum(axis=0)
-        pl.store(o_ref, _01 + (pl.ds(t, 1), slice(None)),
-                 out[None, None, None, :].astype(o_ref.dtype))
+        o_ref[0, 0, pl.ds(t, 1), :] = out[None, :].astype(o_ref.dtype)
         return w[:, None] * S + kv
 
     S0 = jnp.zeros((hd, hd), jnp.float32)
@@ -47,7 +41,6 @@ def wkv6(r, k, v, w, u, *, interpret: bool = True) -> jnp.ndarray:
     """r/k/v: [b, h, T, hd]; w: [b, h, T, hd] decay in (0,1); u: [h, hd].
     Returns out [b, h, T, hd]."""
     b, h, T, hd = r.shape
-    import functools
     kern = functools.partial(_wkv6_kernel, seq=T)
     return pl.pallas_call(
         kern,
@@ -57,9 +50,9 @@ def wkv6(r, k, v, w, u, *, interpret: bool = True) -> jnp.ndarray:
             pl.BlockSpec((1, 1, T, hd), lambda bi, hi: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, T, hd), lambda bi, hi: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, T, hd), lambda bi, hi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, hd), lambda bi, hi: (hi, 0)),
+            pl.BlockSpec((1, 1, hd), lambda bi, hi: (hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, T, hd), lambda bi, hi: (bi, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, T, hd), r.dtype),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u[:, None, :])

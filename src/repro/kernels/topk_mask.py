@@ -5,10 +5,10 @@ algorithm) splits global magnitude top-k into three stages:
 
   1. ``blocked_topk_stats`` — ONE memory-bound pass over the flat delta:
      each grid block packs its ``|x| >= lo`` candidate mask into uint32
-     words (bit i of word w == element w*32+i survives the bracket) and
-     emits its candidate count.  The packed words are the per-block
-     magnitude statistics everything downstream runs on — N/32 words
-     instead of N floats.
+     words (bit i of word w == element w*32+i survives the bracket); the
+     per-block candidate counts are the popcounts of those words.  The
+     packed words are the per-block magnitude statistics everything
+     downstream runs on — N/32 words instead of N floats.
   2. a tiny host-side refinement (jnp over <= k + margin candidates) that
      extracts candidate positions from the packed words and picks the
      EXACT global threshold tau plus the tie budget,
@@ -24,6 +24,12 @@ which is why the exact-k variant exists.
 ``blocked_topk_sparsify`` chains the three stages end to end (two kernel
 launches + the tiny refinement) and falls back to a dense ``lax.top_k``
 mask when the sampled bracket misses — exact either way.
+
+Every kernel walks the bus in the (8, 1024) tiles of
+kernels/vc_asgd_update.py, one BLOCK per grid step.  Mosaic lowers neither
+a lane-splitting reshape nor ``cumsum``, so the stats pass packs its bits
+with one exact 0/1 x 2^b matmul per tile, and the exact emit takes its
+tie ranks from a log-step prefix sum built on ``pltpu.roll``.
 """
 from __future__ import annotations
 
@@ -31,12 +37,27 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.vc_asgd_update import _note_launch
+from repro.core.flat import BLOCK
+from repro.kernels.vc_asgd_update import LANES, ROWS, SMEM, TILE, _note_launch
 
-BLOCK = 8 * 1024
 WORDS = BLOCK // 32        # packed uint32 candidate words per block
+ROW_WORDS = LANES // 32    # words per tile row
+
+
+def _tiles(x: jnp.ndarray):
+    """Flat view of ``x`` as [nb * ROWS, LANES], zero-padded to a BLOCK
+    multiple; returns (tiles, nb, unpad)."""
+    n = x.size
+    nb = -(-n // BLOCK)
+    xf = x.reshape(-1)
+    if nb * BLOCK - n:
+        xf = jnp.pad(xf, (0, nb * BLOCK - n))
+    unpad = lambda t: t.reshape(-1)[:n].reshape(x.shape)
+    return xf.reshape(nb * ROWS, LANES), nb, unpad
 
 
 def _mask_kernel(scal_ref, x_ref, keep_ref, res_ref):
@@ -51,41 +72,43 @@ def threshold_sparsify(x: jnp.ndarray, tau, *, interpret: bool = True):
     """Returns (kept, residual): kept has |x| >= tau entries, residual the
     rest; kept + residual == x exactly.  NOT exact-k under ties at tau —
     use threshold_sparsify_exact for deterministic-k."""
-    n = x.size
-    nb = -(-n // BLOCK)
-    pad = nb * BLOCK - n
-    xf = x.reshape(-1)
-    if pad:
-        xf = jnp.pad(xf, (0, pad))
-    xf = xf.reshape(nb, BLOCK)
-    scal = jnp.asarray([tau], jnp.float32)
+    xt, nb, unpad = _tiles(x)
+    out = jax.ShapeDtypeStruct(xt.shape, x.dtype)
     _note_launch()
     kept, res = pl.pallas_call(
         _mask_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, BLOCK), x.dtype),
-                   jax.ShapeDtypeStruct((nb, BLOCK), x.dtype)],
+        in_specs=[SMEM, TILE],
+        out_specs=[TILE, TILE],
+        out_shape=[out, out],
         interpret=interpret,
-    )(scal, xf)
-    unpad = lambda t: t.reshape(-1)[:n].reshape(x.shape)
+    )(jnp.asarray([tau], jnp.float32), xt)
     return unpad(kept), unpad(res)
 
 
-def _stats_kernel(scal_ref, x_ref, words_ref, cnt_ref):
+def _pack_matrix() -> jnp.ndarray:
+    """[LANES, 128] bf16: column j*32 + w sums byte j of word w of a tile
+    row (entries 2^(b % 8) for the lanes of that byte, else 0).  Each byte
+    is a sum of at most 8 distinct powers of two below 256, exact in bf16
+    products accumulated in f32."""
+    lane = np.arange(LANES)[:, None]
+    col = np.arange(4 * ROW_WORDS)[None, :]
+    bit = lane % 32
+    hit = (lane // 32 == col % ROW_WORDS) & (bit // 8 == col // ROW_WORDS)
+    return jnp.asarray(np.where(hit, 2.0 ** (bit % 8), 0.0), jnp.bfloat16)
+
+
+def _stats_kernel(scal_ref, x_ref, p_ref, words_ref):
     lo = scal_ref[0]
-    x = x_ref[...].astype(jnp.float32)                       # [1, BLOCK]
-    bits = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.uint32)
-    keep = bits >= lo
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (1, WORDS, 32), 2)
-    packed = jnp.sum(jnp.where(keep.reshape(1, WORDS, 32),
-                               jnp.uint32(1) << lane, jnp.uint32(0)),
-                     axis=2, dtype=jnp.uint32)
-    words_ref[...] = packed
-    cnt_ref[...] = jnp.sum(keep.astype(jnp.int32), axis=1)
+    x = x_ref[...].astype(jnp.float32)                       # [ROWS, LANES]
+    bits = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32)
+    keep = (bits >= lo).astype(jnp.bfloat16)
+    b = jnp.dot(keep, p_ref[...],
+                preferred_element_type=jnp.float32).astype(jnp.int32)
+    w = ROW_WORDS
+    packed = (b[:, 0:w] | (b[:, w:2 * w] << 8) | (b[:, 2 * w:3 * w] << 16)
+              | (b[:, 3 * w:4 * w] << 24))                   # [ROWS, w]
+    words_ref[...] = jax.lax.bitcast_convert_type(packed, jnp.uint32)
 
 
 def blocked_topk_stats(x: jnp.ndarray, lo, *, interpret: bool = True):
@@ -96,39 +119,49 @@ def blocked_topk_stats(x: jnp.ndarray, lo, *, interpret: bool = True):
     > 0 so zero tail padding never counts as a candidate.  Returns
     (words [nb, BLOCK//32] uint32 packed candidate masks,
      counts [nb] int32 per-block candidate counts)."""
-    n = x.size
-    nb = -(-n // BLOCK)
-    pad = nb * BLOCK - n
-    xf = x.reshape(-1)
-    if pad:
-        xf = jnp.pad(xf, (0, pad))
-    xf = xf.reshape(nb, BLOCK)
-    scal = jnp.asarray([lo], jnp.uint32)
+    xt, nb, _ = _tiles(x)
+    lo_i = jax.lax.bitcast_convert_type(jnp.asarray(lo, jnp.uint32),
+                                        jnp.int32)
     _note_launch()
-    words, counts = pl.pallas_call(
+    words = pl.pallas_call(
         _stats_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, WORDS), lambda i: (i, 0)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((nb, WORDS), jnp.uint32),
-                   jax.ShapeDtypeStruct((nb,), jnp.int32)],
+        in_specs=[SMEM, TILE,
+                  pl.BlockSpec((LANES, 4 * ROW_WORDS), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((ROWS, ROW_WORDS), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb * ROWS, ROW_WORDS), jnp.uint32),
         interpret=interpret,
-    )(scal, xf)
+    )(lo_i.reshape(1), xt, _pack_matrix())
+    words = words.reshape(nb, WORDS)
+    counts = jnp.sum(jax.lax.population_count(words).astype(jnp.int32),
+                     axis=1)
     return words, counts
+
+
+def _inclusive_prefix(c: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Hillis-Steele inclusive prefix sum along ``axis`` (log2 steps)."""
+    n = c.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, c.shape, axis)
+    shift = 1
+    while shift < n:
+        c = c + jnp.where(idx >= shift, pltpu.roll(c, shift, axis), 0)
+        shift *= 2
+    return c
 
 
 def _exact_kernel(tau_ref, bud_ref, ts_ref, x_ref, keep_ref, res_ref):
     tau = tau_ref[0]
     budget = bud_ref[0]
-    start = ts_ref[0]
-    x = x_ref[...].astype(jnp.float32)
+    start = ts_ref[pl.program_id(0)]
+    x = x_ref[...].astype(jnp.float32)                       # [ROWS, LANES]
     mag = jnp.abs(x)
     gt = mag > tau
     tie = mag == tau
     tie_i = tie.astype(jnp.int32)
-    rank = start + jnp.cumsum(tie_i, axis=1) - tie_i   # global tie rank
+    row = _inclusive_prefix(tie_i, 1)                       # within a row
+    tot = jnp.broadcast_to(row[:, LANES - 1:], row.shape)
+    before = _inclusive_prefix(tot, 0) - tot                # earlier rows
+    rank = start + before + row - tie_i                     # global tie rank
     keep_m = gt | (tie & (rank < budget))
     kept = jnp.where(keep_m, x, 0.0)
     keep_ref[...] = kept.astype(keep_ref.dtype)
@@ -140,32 +173,22 @@ def threshold_sparsify_exact(x: jnp.ndarray, tau, tie_start, tie_budget, *,
     """Exact-k kept/residual emit: keeps |x| > tau unconditionally and
     |x| == tau only while the global tie rank stays below ``tie_budget``
     (``tie_start[b]`` = ties in blocks before b; lowest flat index wins,
-    lax.top_k's tie rule).  kept + residual == x exactly."""
-    n = x.size
-    nb = -(-n // BLOCK)
-    pad = nb * BLOCK - n
-    xf = x.reshape(-1)
-    if pad:
-        xf = jnp.pad(xf, (0, pad))
-    xf = xf.reshape(nb, BLOCK)
-    tau_s = jnp.asarray([tau], jnp.float32)
-    bud_s = jnp.asarray([tie_budget], jnp.int32)
+    lax.top_k's tie rule).  kept + residual == x exactly.  ``tie_start``
+    lives in SMEM whole (1 MiB on v5e: up to ~256k blocks)."""
+    xt, nb, unpad = _tiles(x)
+    tau_s = jnp.asarray(tau, jnp.float32).reshape(1)
+    bud_s = jnp.asarray(tie_budget, jnp.int32).reshape(1)
     ts = jnp.asarray(tie_start, jnp.int32).reshape(nb)
+    out = jax.ShapeDtypeStruct(xt.shape, x.dtype)
     _note_launch()
     kept, res = pl.pallas_call(
         _exact_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec((1,), lambda i: (i,)),
-                  pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, BLOCK), x.dtype),
-                   jax.ShapeDtypeStruct((nb, BLOCK), x.dtype)],
+        in_specs=[SMEM, SMEM, SMEM, TILE],
+        out_specs=[TILE, TILE],
+        out_shape=[out, out],
         interpret=interpret,
-    )(tau_s, bud_s, ts, xf)
-    unpad = lambda t: t.reshape(-1)[:n].reshape(x.shape)
+    )(tau_s, bud_s, ts, xt)
     return unpad(kept), unpad(res)
 
 

@@ -7,13 +7,16 @@ optional DC-ASGD delay-compensation term, (c) the staleness-damped
 effective alpha, and (d) the whole Eq. 2 multi-client reduction — one HBM
 pass for all streams instead of several.
 
-TPU adaptation (DESIGN.md §2): parameters ride the flat bus
-(core/flat.py): one contiguous 1-D buffer, zero-padded to a BLOCK
-multiple, tiled into (1, BLOCK)-element VMEM blocks (multiples of the
-8x128 vector tile); the grid walks the flat buffer.  Scalars (alpha, lam,
-Eq. 2 weights) ride in ANY memory.  The ``*_flat`` entry points take
-pre-padded buffers and launch exactly ONE ``pallas_call`` for the whole
-model; the legacy per-tensor entry points pad-and-reshape on the way in.
+TPU adaptation: parameters ride the flat bus (core/flat.py): one
+contiguous 1-D buffer, zero-padded to a BLOCK multiple.  Each kernel views
+it as ``[nb * ROWS, LANES]`` and walks it in ``(ROWS, LANES) = (8, 1024)``
+VMEM tiles, one BLOCK of contiguous elements per grid step: Mosaic needs
+the last two block dims divisible by 8 and 128, which a ``(1, BLOCK)``
+block is not.  The row-major view keeps the bus order, so padding and the
+wire bytes are the same as for the 1-D buffer.  Scalars (alpha, lam, Eq. 2
+weights, Adam hyperparameters) ride in SMEM.  The ``*_flat`` entry points
+take pre-padded buffers and launch exactly ONE ``pallas_call`` for the
+whole model; the per-tensor entry points pad-and-reshape on the way in.
 
 ``launch_count()`` counts ``pallas_call`` invocations (trace-time) — the
 benchmark/test evidence that the flat path is one launch per assimilation
@@ -26,8 +29,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flat import BLOCK
+
+ROWS = 8                   # sublanes of one grid tile
+LANES = BLOCK // ROWS      # 1024: one tile holds one BLOCK of the bus
+SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+TILE = pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))
 
 _launches = 0
 
@@ -68,12 +77,11 @@ def _dc_lerp_kernel(scal_ref, s_ref, c_ref, g_ref, b_ref, o_ref):
 
 def _assimilate_kernel(w_ref, s_ref, c_ref, o_ref, *, n_clients: int):
     """Eq. 2: acc = w0*s + sum_j w_{j+1}*c_j, accumulated in arrival order
-    (bit-identical to folding Eq. 1) over one [n_clients, 1, BLOCK] tile."""
-    acc = w_ref[0] * s_ref[...].astype(jnp.float32)          # [1, BLOCK]
+    (bit-identical to folding Eq. 1) over one [n_clients, ROWS, LANES]
+    tile."""
+    acc = w_ref[0] * s_ref[...].astype(jnp.float32)          # [ROWS, LANES]
     for j in range(n_clients):
-        cj = pl.load(c_ref, (pl.dslice(j, 1), pl.dslice(0, 1),
-                             slice(None)))[0]                # [1, BLOCK]
-        acc = acc + w_ref[j + 1] * cj.astype(jnp.float32)
+        acc = acc + w_ref[j + 1] * c_ref[j].astype(jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -96,24 +104,27 @@ def _adam_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref,
 
 
 def _easgd_kernel(scal_ref, c_ref, x_ref, co_ref, xo_ref, *, n_replicas: int):
-    """Simultaneous elastic update over one [n_replicas, 1, BLOCK] tile:
-    center moves by beta * sum_j (x_j - c), every replica moves toward the
-    center by beta * (x_j - c) — one pass for the whole pod."""
+    """Simultaneous elastic update over one [n_replicas, ROWS, LANES]
+    tile: center moves by beta * sum_j (x_j - c), every replica moves
+    toward the center by beta * (x_j - c) — one pass for the whole pod."""
     beta = scal_ref[0]
-    c = c_ref[...].astype(jnp.float32)                       # [1, BLOCK]
+    c = c_ref[...].astype(jnp.float32)                       # [ROWS, LANES]
     acc = jnp.zeros_like(c)
     for j in range(n_replicas):
-        xj = pl.load(x_ref, (pl.dslice(j, 1), pl.dslice(0, 1),
-                             slice(None)))[0].astype(jnp.float32)
+        xj = x_ref[j].astype(jnp.float32)
         diff = xj - c
         acc = acc + diff
-        pl.store(xo_ref, (pl.dslice(j, 1), pl.dslice(0, 1), slice(None)),
-                 (xj - beta * diff).astype(xo_ref.dtype)[None])
+        xo_ref[j] = (xj - beta * diff).astype(xo_ref.dtype)
     co_ref[...] = (c + beta * acc).astype(co_ref.dtype)
 
 
+def _scalars(values) -> jnp.ndarray:
+    return jnp.stack([jnp.asarray(x, jnp.float32).reshape(()) for x in values])
+
+
 def _blocked_call(kernel, scalars, arrays, *, interpret: bool):
-    """Flatten every operand to [nb, BLOCK] (zero-padded) and run the grid."""
+    """View every operand as [nb * ROWS, LANES] (zero-padded to a BLOCK
+    multiple) and run the grid, one BLOCK per step."""
     x0 = arrays[0]
     n = x0.size
     nb = -(-n // BLOCK)
@@ -123,20 +134,18 @@ def _blocked_call(kernel, scalars, arrays, *, interpret: bool):
         f = x.reshape(-1)
         if pad:
             f = jnp.pad(f, (0, pad))
-        return f.reshape(nb, BLOCK)
+        return f.reshape(nb * ROWS, LANES)
 
     flats = [prep(x) for x in arrays]
-    scal = jnp.stack([jnp.asarray(s, jnp.float32).reshape(()) for s in scalars])
     _note_launch()
     out = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)) for _ in flats],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, BLOCK), x0.dtype),
+        in_specs=[SMEM] + [TILE for _ in flats],
+        out_specs=TILE,
+        out_shape=jax.ShapeDtypeStruct((nb * ROWS, LANES), x0.dtype),
         interpret=interpret,
-    )(scal, *flats)
+    )(_scalars(scalars), *flats)
     out = out.reshape(-1)
     if pad:
         out = out[:n]
@@ -199,23 +208,19 @@ def assimilate_flat(server: jnp.ndarray, clients: jnp.ndarray, weights,
                          f"got {clients.shape}")
     if len(weights) != n_clients + 1:
         raise ValueError(f"need {n_clients + 1} weights, got {len(weights)}")
-    w = jnp.stack([jnp.asarray(x, jnp.float32).reshape(()) for x in weights])
-    s2 = server.reshape(nb, BLOCK)
-    c3 = clients.reshape(n_clients, nb, BLOCK)
     kern = functools.partial(_assimilate_kernel, n_clients=n_clients)
     _note_launch()
     out = pl.pallas_call(
         kern,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((n_clients, 1, BLOCK), lambda i: (0, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, BLOCK), server.dtype),
+        in_specs=[SMEM, TILE,
+                  pl.BlockSpec((n_clients, ROWS, LANES),
+                               lambda i: (0, i, 0))],
+        out_specs=TILE,
+        out_shape=jax.ShapeDtypeStruct((nb * ROWS, LANES), server.dtype),
         interpret=interpret,
-    )(w, s2, c3)
+    )(_scalars(weights), server.reshape(nb * ROWS, LANES),
+      clients.reshape(n_clients, nb * ROWS, LANES))
     return out.reshape(-1)
 
 
@@ -230,21 +235,19 @@ def adam_update_flat(p, g, m, v, lr, b1, b2, eps, weight_decay, c1, c2,
         if buf.shape != p.shape:
             raise ValueError(f"{name} lane must match params lane "
                              f"{p.shape}, got {buf.shape}")
-    scal = jnp.stack([jnp.asarray(x, jnp.float32).reshape(())
-                      for x in (lr, b1, b2, eps, weight_decay, c1, c2)])
-    blk = pl.BlockSpec((1, BLOCK), lambda i: (i, 0))
+    scal = _scalars((lr, b1, b2, eps, weight_decay, c1, c2))
+    tiles = lambda x: x.reshape(nb * ROWS, LANES)
     _note_launch()
     po, mo, vo = pl.pallas_call(
         _adam_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY), blk, blk, blk, blk],
-        out_specs=(blk, blk, blk),
-        out_shape=(jax.ShapeDtypeStruct((nb, BLOCK), p.dtype),
-                   jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32)),
+        in_specs=[SMEM, TILE, TILE, TILE, TILE],
+        out_specs=(TILE, TILE, TILE),
+        out_shape=(jax.ShapeDtypeStruct((nb * ROWS, LANES), p.dtype),
+                   jax.ShapeDtypeStruct((nb * ROWS, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((nb * ROWS, LANES), jnp.float32)),
         interpret=interpret,
-    )(scal, p.reshape(nb, BLOCK), g.reshape(nb, BLOCK),
-      m.reshape(nb, BLOCK), v.reshape(nb, BLOCK))
+    )(scal, tiles(p), tiles(g), tiles(m), tiles(v))
     return po.reshape(-1), mo.reshape(-1), vo.reshape(-1)
 
 
@@ -257,21 +260,18 @@ def easgd_elastic_flat(center, replicas, beta, *, interpret: bool = True):
     if replicas.shape != (n, center.size):
         raise ValueError(f"replicas must be [n, {center.size}], "
                          f"got {replicas.shape}")
-    scal = jnp.asarray([beta], jnp.float32)
     kern = functools.partial(_easgd_kernel, n_replicas=n)
+    stack = pl.BlockSpec((n, ROWS, LANES), lambda i: (0, i, 0))
     _note_launch()
     co, xo = pl.pallas_call(
         kern,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((n, 1, BLOCK), lambda i: (0, i, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((n, 1, BLOCK), lambda i: (0, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((nb, BLOCK), center.dtype),
-                   jax.ShapeDtypeStruct((n, nb, BLOCK), replicas.dtype)),
+        in_specs=[SMEM, TILE, stack],
+        out_specs=(TILE, stack),
+        out_shape=(jax.ShapeDtypeStruct((nb * ROWS, LANES), center.dtype),
+                   jax.ShapeDtypeStruct((n, nb * ROWS, LANES),
+                                        replicas.dtype)),
         interpret=interpret,
-    )(scal, center.reshape(nb, BLOCK), replicas.reshape(n, nb, BLOCK))
+    )(_scalars([beta]), center.reshape(nb * ROWS, LANES),
+      replicas.reshape(n, nb * ROWS, LANES))
     return co.reshape(-1), xo.reshape(n, -1)

@@ -1,10 +1,14 @@
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes and extract memory / cost / collective analysis.
 
-MUST set the device-count flag before ANY other import (jax locks the
-device count on first init).
+A CPU-only tool: it compiles for 512 virtual CPU devices, so it checks
+shardings and collectives but says nothing about Mosaic or HBM fit (for
+those, tests/test_tpu_compile.py compiles for a described TPU).  It pins
+``JAX_PLATFORMS=cpu`` and the device-count flag before ANY other import
+(jax locks the device count on first init).
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512").strip()
 

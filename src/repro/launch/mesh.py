@@ -5,28 +5,24 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: newer jax wants explicit
-    ``axis_types=(AxisType.Auto, ...)``; older jax (<=0.4.x) has neither
-    the kwarg nor ``jax.sharding.AxisType``.  Callers (runtime + tests)
-    must route mesh creation through here."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the runtime
+    lays arrays out with NamedShardings and sharding constraints and lets
+    the compiler propagate the rest."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds the 2-pod DCN axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_pod_mesh(n_pods: int, axis: str = "pod"):
@@ -36,4 +32,4 @@ def make_pod_mesh(n_pods: int, axis: str = "pod"):
     per-shard under shard_map with no gather (runtime/sharding.py)."""
     if n_pods < 1:
         raise ValueError(f"n_pods must be >= 1, got {n_pods}")
-    return compat_make_mesh((n_pods,), (axis,))
+    return make_mesh((n_pods,), (axis,))

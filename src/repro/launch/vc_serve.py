@@ -36,6 +36,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import jax
 
+from repro import compile_cache
 from repro.checkpoint import CheckpointManager
 from repro.core import flat as F
 from repro.core.baselines import CompressedVCASGD, VCASGD
@@ -86,6 +87,7 @@ def main(argv=None):
                     help="tiny configuration for the fast test gate")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.smoke:
         args.rounds, args.clients, args.shards = 2, 2, 2
 

@@ -340,7 +340,7 @@ class MeshPlan(NullPlan):
 # at every shard count (tests/test_sharded_flat.py asserts this).
 # ---------------------------------------------------------------------------
 
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 
 def flat_sharding(mesh: Mesh, axis: str = "pod") -> NamedSharding:
@@ -391,7 +391,7 @@ def sharded_lerp_flat(server_buf, client_buf, alpha, mesh: Mesh,
         return R.vc_asgd_lerp(s, c, a_)
 
     return shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis), P()),
-                     out_specs=P(axis), check_rep=False)(
+                     out_specs=P(axis), check_vma=False)(
         server_buf, client_buf, a)
 
 
@@ -418,7 +418,7 @@ def sharded_assimilate_flat(server_buf, clients_buf, weights, mesh: Mesh,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(), P(axis), P(None, axis)),
-                     out_specs=P(axis), check_rep=False)(
+                     out_specs=P(axis), check_vma=False)(
         w, server_buf, clients_buf)
 
 
@@ -446,7 +446,7 @@ def sharded_adam_update_flat(p_buf, g_buf, m_buf, v_buf, lr, b1, b2, eps,
     blk = P(axis)
     return shard_map(local, mesh=mesh,
                      in_specs=(P(), blk, blk, blk, blk),
-                     out_specs=(blk, blk, blk), check_rep=False)(
+                     out_specs=(blk, blk, blk), check_vma=False)(
         scal, p_buf, g_buf, m_buf, v_buf)
 
 
@@ -463,7 +463,7 @@ def sharded_broadcast_flat(server_buf, n_pods: int, mesh: Mesh,
         return jnp.broadcast_to(s[None], (n_pods,) + s.shape)
 
     return shard_map(local, mesh=mesh, in_specs=(P(axis),),
-                     out_specs=P(None, axis), check_rep=False)(server_buf)
+                     out_specs=P(None, axis), check_vma=False)(server_buf)
 
 
 def sharded_easgd_flat(center_buf, replicas_buf, beta, mesh: Mesh,
@@ -482,7 +482,7 @@ def sharded_easgd_flat(center_buf, replicas_buf, beta, mesh: Mesh,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axis), P(None, axis), P()),
-                     out_specs=(P(axis), P(None, axis)), check_rep=False)(
+                     out_specs=(P(axis), P(None, axis)), check_vma=False)(
         center_buf, replicas_buf, b)
 
 

@@ -87,12 +87,10 @@ def assimilate_flat(server_buf, islands_buf, w, w_s, *,
 
     if mesh is None:
         return local(server_buf, islands_buf, w, w_s)
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as SP
-    return shard_map(local, mesh=mesh,
-                     in_specs=(SP(shard_axis), SP(None, shard_axis),
-                               SP(), SP()),
-                     out_specs=SP(shard_axis), check_rep=False)(
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(shard_axis), P(None, shard_axis),
+                                   P(), P()),
+                         out_specs=P(shard_axis), check_vma=False)(
         server_buf, islands_buf, w, jnp.asarray(w_s, jnp.float32))
 
 
@@ -138,35 +136,15 @@ def assimilate_islands_per_leaf(server, islands, w, w_s):
     return jax.tree.map(merge, server, islands)
 
 
-def make_vc_round(model: Model, plan: MeshPlan, n_pods: int,
-                  local_steps: int = 4, optimizer=None,
-                  clip_norm: float = 1.0, pod_axis: str = "pod",
-                  flat_shard_axis: Optional[str] = None,
-                  use_kernel: bool = False):
-    """Returns vc_round(server, islands, opts, batches, alpha, survivors)
-    -> (server', islands', opts', metrics).
-
-    islands/opts carry a leading [n_pods] dim; batches carry
-    [n_pods, local_steps, ...].
-
-    Assimilation rides the FLAT bus: the trained islands are flattened
-    once into a [n_pods, padded] matrix, the server once onto the same
-    layout, and Eq. 2 is ONE masked weighted reduction over contiguous
-    buffers (``assimilate_flat``) instead of a per-leaf tree walk — the
-    same code path as the simulator's schemes.  With ``flat_shard_axis``
-    set (a mesh axis of ``plan.mesh``), the buffers are padded so every
-    device owns a contiguous BLOCK-multiple segment and the reduction
-    runs per shard under shard_map with no gather."""
+def make_island_trainer(model: Model, plan: MeshPlan, local_steps: int = 4,
+                        optimizer=None, clip_norm: float = 1.0):
+    """Returns train_islands(islands, opts, batches) -> (islands', opts',
+    losses [n_pods]): ``local_steps`` steps per island (scan over steps),
+    vmapped over the leading pod dim — NO cross-pod collective (the
+    paper's asynchronous, barrier-free client training)."""
     optimizer = optimizer or Adam(lr=3e-4)
-    from repro.core import flat as F
-    pad_to = F.BLOCK
-    mesh = None
-    if flat_shard_axis is not None:
-        mesh = plan.mesh
-        pad_to = F.BLOCK * int(mesh.shape[flat_shard_axis])
 
     def local_train(params, opt_state, steps_batch):
-        """k local steps on one island (scan over steps)."""
         def step(carry, batch):
             p, o = carry
             (loss, _), grads = jax.value_and_grad(
@@ -178,11 +156,33 @@ def make_vc_round(model: Model, plan: MeshPlan, n_pods: int,
             step, (params, opt_state), steps_batch)
         return params, opt_state, losses.mean()
 
-    def vc_round(server, islands, opts, batches, alpha, survivors):
-        # 1) island-local training, no cross-pod sync
-        islands, opts, losses = jax.vmap(local_train)(islands, opts, batches)
-        # 2) Eq. 2 assimilation on the flat bus: flatten at the boundary
-        #    (once per round), reduce contiguous segments, zero leaf loops
+    return jax.vmap(local_train)
+
+
+def make_island_assimilator(plan: MeshPlan, n_pods: int,
+                            flat_shard_axis: Optional[str] = None,
+                            use_kernel: bool = False):
+    """Returns assimilate(server, islands, alpha, survivors) -> (server',
+    islands'): Eq. 2 on the flat bus, then redistribution.
+
+    The trained islands are flattened once into a [n_pods, padded]
+    matrix, the server once onto the same layout, and Eq. 2 is ONE masked
+    weighted reduction over contiguous buffers (``assimilate_flat``)
+    instead of a per-leaf tree walk — the same code path as the
+    simulator's schemes.  With ``flat_shard_axis`` set (a mesh axis of
+    ``plan.mesh``), the buffers are padded so every device owns a
+    contiguous BLOCK-multiple segment and the reduction runs per shard
+    under shard_map with no gather.  Every island then restarts from the
+    server snapshot via a per-shard broadcast, bit-identical to the
+    retained per-leaf broadcast oracle."""
+    from repro.core import flat as F
+    pad_to = F.BLOCK
+    mesh = None
+    if flat_shard_axis is not None:
+        mesh = plan.mesh
+        pad_to = F.BLOCK * int(mesh.shape[flat_shard_axis])
+
+    def assimilate(server, islands, alpha, survivors):
         w, w_s = island_weights(n_pods, alpha, survivors)
         isl_buf, spec = F.flatten_batched(islands, pad_to=pad_to)
         s_buf = F.flatten_like(server, spec)
@@ -190,13 +190,33 @@ def make_vc_round(model: Model, plan: MeshPlan, n_pods: int,
                                   shard_axis=flat_shard_axis,
                                   use_kernel=use_kernel)
         server = F.unflatten(F.FlatParams(out_buf, spec))
-        # 3) redistribution on the bus: every island restarts from the
-        #    server snapshot via a per-shard broadcast (sharded: each
-        #    device copies only its own contiguous segment, no gather) —
-        #    bit-identical to the retained per-leaf broadcast oracle
         isl_out = redistribute_flat(out_buf, n_pods, mesh=mesh,
                                     shard_axis=flat_shard_axis)
-        islands = F.unflatten_batched(isl_out, spec)
+        return server, F.unflatten_batched(isl_out, spec)
+
+    return assimilate
+
+
+def make_vc_round(model: Model, plan: MeshPlan, n_pods: int,
+                  local_steps: int = 4, optimizer=None,
+                  clip_norm: float = 1.0,
+                  flat_shard_axis: Optional[str] = None,
+                  use_kernel: bool = False):
+    """Returns vc_round(server, islands, opts, batches, alpha, survivors)
+    -> (server', islands', opts', metrics): island-local training
+    (``make_island_trainer``), then Eq. 2 assimilation and redistribution
+    on the flat bus (``make_island_assimilator``).
+
+    islands/opts carry a leading [n_pods] dim; batches carry
+    [n_pods, local_steps, ...]."""
+    train_islands = make_island_trainer(model, plan, local_steps, optimizer,
+                                        clip_norm)
+    assimilate = make_island_assimilator(plan, n_pods, flat_shard_axis,
+                                         use_kernel)
+
+    def vc_round(server, islands, opts, batches, alpha, survivors):
+        islands, opts, losses = train_islands(islands, opts, batches)
+        server, islands = assimilate(server, islands, alpha, survivors)
         return server, islands, opts, {"loss": losses.mean()}
 
     return vc_round

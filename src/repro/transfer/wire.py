@@ -209,10 +209,9 @@ def encode_shard(seg, *, shard: int, n_shards: int, round: int = 0) -> bytes:
 
 # fused encode leg: the body (values || scales || indices bytes) is packed
 # into ONE device buffer (kernels/ref.py::pack_body — bitcast+concat, zero
-# arithmetic, so the bytes are exactly the payload arrays' bytes; the
-# Pallas single-launch form is kernels/sparse_pack.py) and crosses the
-# device->host boundary in ONE transfer, vs the three array transfers +
-# Python concat the old encoder paid per frame.  jit caches by (k, ng)
+# arithmetic, so the bytes are exactly the payload arrays' bytes) and
+# crosses the device->host boundary in ONE transfer, vs the three array
+# transfers + Python concat the old encoder paid per frame.  jit caches by (k, ng)
 # shape, so steady-state rounds reuse the compiled pack.
 _pack_body_dev = None
 

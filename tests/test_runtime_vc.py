@@ -14,7 +14,7 @@ from repro.runtime.sharding import MeshPlan
 from repro.runtime.vc_runtime import (compressed_assimilate, island_weights,
                                       make_vc_round, redistribute_flat,
                                       redistribute_per_leaf)
-from repro.launch.mesh import compat_make_mesh, make_pod_mesh
+from repro.launch.mesh import make_mesh, make_pod_mesh
 
 
 def test_island_weights_match_eq2():
@@ -34,7 +34,7 @@ def test_island_weights_survivor_mask():
 def test_vc_round_runs_and_learns():
     cfg = get_reduced("internlm2-1.8b")
     model = build_model(cfg)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = MeshPlan.build(cfg, mesh)
     opt = Adam(lr=1e-3)
     n_pods, k = 2, 2
@@ -59,7 +59,7 @@ def test_vc_round_dead_island_is_ignored():
     """A dead island's (stale) params must not affect the server."""
     cfg = get_reduced("internlm2-1.8b")
     model = build_model(cfg)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = MeshPlan.build(cfg, mesh)
     opt = Adam(lr=1e-3)
     vc_round = make_vc_round(model, plan, 2, 1, opt)

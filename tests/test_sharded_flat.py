@@ -374,12 +374,12 @@ def test_vc_round_flat_sharded_on_pod_mesh():
         from repro.models.registry import build_model
         from repro.optim import Adam
         from repro.runtime.sharding import MeshPlan
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.runtime.vc_runtime import make_vc_round
 
         cfg = get_reduced("internlm2-1.8b")
         model = build_model(cfg)
-        mesh = compat_make_mesh((2, 1, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 1, 2), ("pod", "data", "model"))
         plan = MeshPlan.build(cfg, mesh)
         opt = Adam(lr=1e-3)
         key = jax.random.PRNGKey(0)

@@ -32,7 +32,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.models.registry import build_model
         from repro.optim import Adam
         from repro.runtime.sharding import MeshPlan
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.runtime.train import make_train_step, shardings_for_train
         from repro.data import make_batch_for
 
@@ -47,7 +47,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.models.plan import NULL_PLAN
         loss1 = model.loss(params, batch)[0]
 
-        mesh = compat_make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         plan = MeshPlan.build(cfg, mesh)
         step = make_train_step(model, plan, opt)
         ins, outs = shardings_for_train(model, plan, opt, batch)
@@ -69,7 +69,7 @@ def test_cp_arch_sharded_matches_single_device():
         from repro.configs import get_reduced
         from repro.models.registry import build_model
         from repro.runtime.sharding import MeshPlan
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.data import make_batch_for
 
         cfg = get_reduced("qwen2.5-14b").replace(compute_dtype="float32")
@@ -78,7 +78,7 @@ def test_cp_arch_sharded_matches_single_device():
         batch = make_batch_for(cfg, 4, 64)
         lg1 = model.forward(params, batch)
 
-        mesh = compat_make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         # reduced config is tiny (d_model 80), so the planner would choose
         # "local"; force the CP path the full config takes (40 heads % 16)
         plan = MeshPlan.build(cfg, mesh, attn_mode="cp")
@@ -100,7 +100,7 @@ def test_decode_cache_seq_sharded_matches():
         from repro.configs import get_reduced
         from repro.models.registry import build_model
         from repro.runtime.sharding import MeshPlan
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.data import make_batch_for
 
         cfg = get_reduced("mixtral-8x7b").replace(compute_dtype="float32")
@@ -112,7 +112,7 @@ def test_decode_cache_seq_sharded_matches():
         tok = jnp.argmax(lg_p1[:, :cfg.vocab_size], -1).astype(jnp.int32)
         lg_d1, _ = model.decode_step(params, c1, tok, jnp.asarray(32, jnp.int32))
 
-        mesh = compat_make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         plan = MeshPlan.build(cfg, mesh, decode_batch=4)
         with mesh:
             lg_p2, c2 = jax.jit(lambda p, b: model.prefill(p, b, plan=plan))(params, batch)
@@ -136,12 +136,12 @@ def test_vc_round_multi_pod_elasticity():
         from repro.models.registry import build_model
         from repro.optim import Adam
         from repro.runtime.sharding import MeshPlan
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.runtime.vc_runtime import island_shardings, make_vc_round
 
         cfg = get_reduced("internlm2-1.8b")
         model = build_model(cfg)
-        mesh = compat_make_mesh((2, 1, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 1, 2), ("pod", "data", "model"))
         plan = MeshPlan.build(cfg, mesh)
         opt = Adam(lr=1e-3)
         vc_round = make_vc_round(model, plan, 2, 2, opt)

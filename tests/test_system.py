@@ -10,7 +10,7 @@ from repro.core.baselines import VCASGD
 from repro.core.simulator import SimConfig, run_simulation
 from repro.core.tasks import MLPTask, make_classification_data
 from repro.core.vc_asgd import var_alpha
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 
 def test_full_system_with_everything_on(tmp_path):
@@ -39,7 +39,7 @@ def test_checkpoint_restart_mid_training(tmp_path):
 
     cfg = get_reduced("internlm2-1.8b")
     model = build_model(cfg)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = MeshPlan.build(cfg, mesh)
     opt = Adam(lr=1e-3)
     vc = make_vc_round(model, plan, 2, 1, opt)
