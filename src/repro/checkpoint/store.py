@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import msgpack
 import numpy as np
 
+from repro import obs
+
 
 def _tree_to_payload(tree) -> Tuple[Dict, list]:
     leaves, treedef = jax.tree.flatten(tree)
@@ -44,23 +46,26 @@ def _tree_to_payload(tree) -> Tuple[Dict, list]:
     return {"treedef": str(treedef), "metas": metas}, bufs
 
 
-def save_checkpoint(path: str | Path, tree, extra: Optional[Dict] = None
-                    ) -> None:
-    """Atomic save: write to a temp file in the same dir, then rename."""
+def save_checkpoint(path: str | Path, tree, extra: Optional[Dict] = None,
+                    *, step: Optional[int] = None) -> None:
+    """Atomic save: write to a temp file in the same dir, then rename.
+    ``step`` tags the writer's spans (``repro.obs``)."""
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
-    header, bufs = _tree_to_payload(tree)
+    with obs.span("vc.writer.serialize", step=step):
+        header, bufs = _tree_to_payload(tree)
     header["extra"] = extra or {}
-    fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
-    try:
-        with os.fdopen(fd, "wb") as f:
-            f.write(msgpack.packb(header, use_bin_type=True))
-            for b in bufs:
-                f.write(msgpack.packb(b, use_bin_type=True))
-        os.replace(tmp, path)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
+    with obs.span("vc.writer.write", step=step):
+        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
+        try:
+            with os.fdopen(fd, "wb") as f:
+                f.write(msgpack.packb(header, use_bin_type=True))
+                for b in bufs:
+                    f.write(msgpack.packb(b, use_bin_type=True))
+            os.replace(tmp, path)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
 
 
 def load_checkpoint(path: str | Path, tree_like) -> Tuple[Any, Dict]:
@@ -90,23 +95,26 @@ def load_checkpoint(path: str | Path, tree_like) -> Tuple[Any, Dict]:
 # load, exactly like load_checkpoint.
 # ---------------------------------------------------------------------------
 
-def save_flat_checkpoint(path: str | Path, fp, extra: Optional[Dict] = None
-                         ) -> None:
-    """Atomic save of a FlatParams: header (layout + extra) + one buffer."""
+def save_flat_checkpoint(path: str | Path, fp, extra: Optional[Dict] = None,
+                         *, step: Optional[int] = None) -> None:
+    """Atomic save of a FlatParams: header (layout + extra) + one buffer.
+    ``step`` tags the writer's spans (``repro.obs``)."""
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
-    buf_dtype, raw = _buf_to_bytes(np.asarray(jax.device_get(fp.buf)))
+    with obs.span("vc.writer.serialize", step=step):
+        buf_dtype, raw = _buf_to_bytes(np.asarray(jax.device_get(fp.buf)))
     header = {"flat": fp.spec.meta(), "buf_dtype": buf_dtype,
               "treedef": str(fp.spec.treedef), "extra": extra or {}}
-    fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
-    try:
-        with os.fdopen(fd, "wb") as f:
-            f.write(msgpack.packb(header, use_bin_type=True))
-            f.write(msgpack.packb(raw, use_bin_type=True))
-        os.replace(tmp, path)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
+    with obs.span("vc.writer.write", step=step):
+        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
+        try:
+            with os.fdopen(fd, "wb") as f:
+                f.write(msgpack.packb(header, use_bin_type=True))
+                f.write(msgpack.packb(raw, use_bin_type=True))
+            os.replace(tmp, path)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
 
 
 def load_flat_checkpoint(path: str | Path, like) -> Tuple[Any, Dict]:
@@ -182,31 +190,35 @@ def _buf_to_bytes(arr: np.ndarray) -> Tuple[str, bytes]:
 # ---------------------------------------------------------------------------
 
 def save_train_checkpoint(path: str | Path, fp, opt,
-                          extra: Optional[Dict] = None) -> None:
+                          extra: Optional[Dict] = None, *,
+                          step: Optional[int] = None) -> None:
     """Atomic save of (FlatParams, FlatOptState): one header + ONE
-    contiguous record laid out as [params | m | v]."""
+    contiguous record laid out as [params | m | v].  ``step`` tags the
+    writer's spans (``repro.obs``)."""
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     if fp.spec.padded != opt.spec.padded or fp.spec.shapes != opt.spec.shapes:
         raise ValueError("params and optimizer state do not share a layout")
-    p_dtype, p_raw = _buf_to_bytes(np.asarray(jax.device_get(fp.buf)))
-    m_raw = np.asarray(jax.device_get(opt.m), np.float32).tobytes()
-    v_raw = np.asarray(jax.device_get(opt.v), np.float32).tobytes()
-    header = {"kind": "flat-train", "flat": fp.spec.meta(),
-              "buf_dtype": p_dtype, "lane_bytes": [len(p_raw), len(m_raw),
-                                                   len(v_raw)],
-              "step": int(jax.device_get(opt.step)),
-              "treedef": str(fp.spec.treedef), "extra": extra or {}}
-    record = b"".join((p_raw, m_raw, v_raw))  # ONE contiguous record
-    fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
-    try:
-        with os.fdopen(fd, "wb") as f:
-            f.write(msgpack.packb(header, use_bin_type=True))
-            f.write(msgpack.packb(record, use_bin_type=True))
-        os.replace(tmp, path)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
+    with obs.span("vc.writer.serialize", step=step):
+        p_dtype, p_raw = _buf_to_bytes(np.asarray(jax.device_get(fp.buf)))
+        m_raw = np.asarray(jax.device_get(opt.m), np.float32).tobytes()
+        v_raw = np.asarray(jax.device_get(opt.v), np.float32).tobytes()
+        header = {"kind": "flat-train", "flat": fp.spec.meta(),
+                  "buf_dtype": p_dtype,
+                  "lane_bytes": [len(p_raw), len(m_raw), len(v_raw)],
+                  "step": int(jax.device_get(opt.step)),
+                  "treedef": str(fp.spec.treedef), "extra": extra or {}}
+        record = b"".join((p_raw, m_raw, v_raw))  # ONE contiguous record
+    with obs.span("vc.writer.write", step=step):
+        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
+        try:
+            with os.fdopen(fd, "wb") as f:
+                f.write(msgpack.packb(header, use_bin_type=True))
+                f.write(msgpack.packb(record, use_bin_type=True))
+            os.replace(tmp, path)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
 
 
 def load_train_checkpoint(path: str | Path, like) -> Tuple[Any, Any, Dict]:
@@ -257,41 +269,63 @@ class CheckpointManager:
         return self.dir / f"ckpt_{step:08d}.msgpack"
 
     def save(self, step: int, tree, extra: Optional[Dict] = None) -> None:
-        self.wait()
-        from repro.core import flat as F
-        flat = isinstance(tree, F.FlatParams)
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+        with obs.span("vc.ckpt.save", step=step):
+            with obs.span("vc.ckpt.join", step=step):
+                self.wait()
+            from repro.core import flat as F
+            flat = isinstance(tree, F.FlatParams)
+            host_tree = self._to_host(step, tree)
 
-        def work():
-            if flat:
-                save_flat_checkpoint(self._path(step), host_tree, extra)
-            else:
-                save_checkpoint(self._path(step), host_tree, extra)
-            self._gc()
+            def work():
+                with obs.span("vc.writer", step=step) as span:
+                    if flat:
+                        save_flat_checkpoint(self._path(step), host_tree,
+                                             extra, step=step)
+                    else:
+                        save_checkpoint(self._path(step), host_tree, extra,
+                                        step=step)
+                    span.set_metadata(bytes=self._path(step).stat().st_size)
+                    with obs.span("vc.writer.retain", step=step):
+                        self._gc()
 
-        if self.async_save:
-            self._pending = threading.Thread(target=work, daemon=True)
-            self._pending.start()
-        else:
-            work()
+            self._start(work)
 
     def save_train(self, step: int, fp, opt,
                    extra: Optional[Dict] = None) -> None:
         """One-pass (params + m + v) snapshot; same retention/async rules
         as save()."""
-        self.wait()
-        host_fp = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), fp)
-        host_opt = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), opt)
+        with obs.span("vc.ckpt.save", step=step):
+            with obs.span("vc.ckpt.join", step=step):
+                self.wait()
+            host_fp, host_opt = self._to_host(step, (fp, opt))
 
-        def work():
-            save_train_checkpoint(self._path(step), host_fp, host_opt, extra)
-            self._gc()
+            def work():
+                with obs.span("vc.writer", step=step) as span:
+                    save_train_checkpoint(self._path(step), host_fp,
+                                          host_opt, extra, step=step)
+                    span.set_metadata(bytes=self._path(step).stat().st_size)
+                    with obs.span("vc.writer.retain", step=step):
+                        self._gc()
 
+            self._start(work)
+
+    def _start(self, work) -> None:
+        """Run ``work`` on the writer thread, or here without async."""
         if self.async_save:
             self._pending = threading.Thread(target=work, daemon=True)
             self._pending.start()
         else:
             work()
+
+    @staticmethod
+    def _to_host(step: int, tree):
+        """``tree`` copied to host arrays, in the span ``vc.ckpt.to_host``
+        with the bytes copied."""
+        with obs.span("vc.ckpt.to_host", step=step) as span:
+            host = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+            span.set_metadata(bytes=sum(a.nbytes for a in
+                                        jax.tree.leaves(host)))
+        return host
 
     def restore_train_or_init(self, like, init_fn):
         """Resume (params, opt state) from the newest train checkpoint or

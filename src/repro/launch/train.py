@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compile_cache
+from repro import compile_cache, obs
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_reduced
 from repro.core.vc_asgd import var_alpha, const_alpha
@@ -115,25 +115,35 @@ def train(cfg: ModelConfig, *, ckpt_dir: str, islands: int = 1,
         log(f"[train] {cfg.describe()}")
         log(f"[train] islands={n_pods} mesh={dict(mesh.shape)} "
             f"resume_round={start_round}")
+        saved = start_round
+        t_log = time.time()
         for rnd in range(start_round, rounds):
-            t0 = time.time()
-            batches = round_batches(cfg, n_pods, local_steps, batch, seq,
-                                    seed=seed * 7919 + rnd)
-            survivors = np.ones((n_pods,), bool)
-            if rnd == preempt_round:
-                survivors[0] = False      # island 0 preempted this round
-                log(f"[train] round {rnd}: island 0 PREEMPTED "
-                    f"(masked out of assimilation)")
-            a = jnp.asarray(alpha_fn(rnd + 1), jnp.float32)
-            server, isl, opts, metrics = step_fn(
-                server, isl, opts, batches, a, jnp.asarray(survivors))
-            loss = float(metrics["loss"])
-            losses.append(loss)
-            log(f"[train] round {rnd:3d} alpha={float(a):.3f} "
-                f"loss={loss:.4f} ({time.time() - t0:.1f}s)")
-            if (rnd + 1) % ckpt_every == 0:
-                ckpt.save(rnd + 1, server, {"round": rnd + 1})
-        ckpt.wait()
+            with obs.step_span("vc.round", rnd):
+                with obs.span("vc.batches", round=rnd):
+                    batches = round_batches(cfg, n_pods, local_steps, batch,
+                                            seq, seed=seed * 7919 + rnd)
+                survivors = np.ones((n_pods,), bool)
+                if rnd == preempt_round:
+                    survivors[0] = False      # island 0 preempted this round
+                    log(f"[train] round {rnd}: island 0 PREEMPTED "
+                        f"(masked out of assimilation)")
+                a = jnp.asarray(alpha_fn(rnd + 1), jnp.float32)
+                with obs.span("vc.dispatch", round=rnd):
+                    server, isl, opts, metrics = step_fn(
+                        server, isl, opts, batches, a, jnp.asarray(survivors))
+                with obs.span("vc.loss_wait", round=rnd):
+                    loss = float(metrics["loss"])
+                losses.append(loss)
+                # a round runs from the previous round's line to this one,
+                # so it holds the previous round's save
+                t_prev, t_log = t_log, time.time()
+                log(f"[train] round {rnd:3d} alpha={float(a):.3f} "
+                    f"loss={loss:.4f} ({t_log - t_prev:.1f}s)")
+                if (rnd + 1) % ckpt_every == 0:
+                    saved = rnd + 1
+                    ckpt.save(saved, server, {"round": saved})
+        with obs.span("vc.ckpt.wait", step=saved):
+            ckpt.wait()
     return {"losses": losses, "server": server, "start_round": start_round}
 
 

@@ -184,15 +184,21 @@ def make_island_assimilator(plan: MeshPlan, n_pods: int,
 
     def assimilate(server, islands, alpha, survivors):
         w, w_s = island_weights(n_pods, alpha, survivors)
-        isl_buf, spec = F.flatten_batched(islands, pad_to=pad_to)
-        s_buf = F.flatten_like(server, spec)
-        out_buf = assimilate_flat(s_buf, isl_buf, w, w_s, mesh=mesh,
-                                  shard_axis=flat_shard_axis,
-                                  use_kernel=use_kernel)
-        server = F.unflatten(F.FlatParams(out_buf, spec))
-        isl_out = redistribute_flat(out_buf, n_pods, mesh=mesh,
-                                    shard_axis=flat_shard_axis)
-        return server, F.unflatten_batched(isl_out, spec)
+        # the scopes name the round's phases in a profiler's trace viewer
+        with jax.named_scope("vc.flatten"):
+            isl_buf, spec = F.flatten_batched(islands, pad_to=pad_to)
+            s_buf = F.flatten_like(server, spec)
+        with jax.named_scope("vc.eq2"):
+            out_buf = assimilate_flat(s_buf, isl_buf, w, w_s, mesh=mesh,
+                                      shard_axis=flat_shard_axis,
+                                      use_kernel=use_kernel)
+        with jax.named_scope("vc.unflatten"):
+            server = F.unflatten(F.FlatParams(out_buf, spec))
+        with jax.named_scope("vc.redistribute"):
+            isl_out = redistribute_flat(out_buf, n_pods, mesh=mesh,
+                                        shard_axis=flat_shard_axis)
+        with jax.named_scope("vc.unflatten"):
+            return server, F.unflatten_batched(isl_out, spec)
 
     return assimilate
 
@@ -215,7 +221,8 @@ def make_vc_round(model: Model, plan: MeshPlan, n_pods: int,
                                          use_kernel)
 
     def vc_round(server, islands, opts, batches, alpha, survivors):
-        islands, opts, losses = train_islands(islands, opts, batches)
+        with jax.named_scope("vc.local_steps"):
+            islands, opts, losses = train_islands(islands, opts, batches)
         server, islands = assimilate(server, islands, alpha, survivors)
         return server, islands, opts, {"loss": losses.mean()}
 
