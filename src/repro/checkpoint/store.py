@@ -13,6 +13,16 @@ Server state on the FlatParams bus (core/flat.py) takes the flat path:
 ``save_flat_checkpoint`` writes the TreeSpec offset table in the header
 and the parameter set as ONE contiguous buffer (no leaf-by-leaf packing);
 the manager routes FlatParams there automatically.
+
+File format, shared by every saver: a msgpack header object, then each
+payload as one msgpack bin object (bfloat16 as its uint16 bits).  The
+payloads are framed by hand (``_write_bin``: the bin header msgpack would
+write, then the array's own buffer) and written through an unbuffered
+file, so no Python-level copy of a payload is made and each ``write``
+syscall runs without the interpreter lock, which the trainer's main thread
+needs meanwhile.  The bytes are those ``msgpack.packb(
+arr.tobytes(), use_bin_type=True)`` gives, so the loaders read them with
+a plain ``msgpack.Unpacker``.
 """
 from __future__ import annotations
 
@@ -32,18 +42,61 @@ import numpy as np
 from repro import obs
 
 
+def _write_all(f, buf) -> None:
+    """Write every byte of ``buf`` to the unbuffered file ``f``, looping
+    over partial writes."""
+    view = memoryview(buf).cast("B")
+    while view:
+        view = view[f.write(view):]
+
+
+def _write_bin(f, *arrays: np.ndarray) -> None:
+    """Write ``arrays``' bytes back to back as ONE msgpack bin object, the
+    bytes ``msgpack.packb(b"".join(a.tobytes() for a in arrays),
+    use_bin_type=True)`` gives, straight from the arrays' buffers (no copy
+    for C-contiguous arrays)."""
+    n = sum(a.nbytes for a in arrays)
+    if n < 1 << 8:
+        _write_all(f, b"\xc4" + n.to_bytes(1, "big"))
+    elif n < 1 << 16:
+        _write_all(f, b"\xc5" + n.to_bytes(2, "big"))
+    elif n < 1 << 32:
+        _write_all(f, b"\xc6" + n.to_bytes(4, "big"))
+    else:
+        raise ValueError("bytes object is too large")
+    for a in arrays:
+        _write_all(f, np.ascontiguousarray(a).reshape(-1).view(np.uint8))
+
+
+def _write_file(path: Path, header: Dict, records, *,
+                step: Optional[int]) -> None:
+    """Atomic write, in the span ``vc.writer.write``: ``header``, then each
+    record (a tuple of arrays, framed as ONE bin object) to a temp file in
+    ``path``'s directory, then the rename over ``path``."""
+    with obs.span("vc.writer.write", step=step):
+        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
+        try:
+            with os.fdopen(fd, "wb", buffering=0) as f:
+                _write_all(f, msgpack.packb(header, use_bin_type=True))
+                for arrays in records:
+                    _write_bin(f, *arrays)
+            os.replace(tmp, path)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+
+
 def _tree_to_payload(tree) -> Tuple[Dict, list]:
+    """The header (dtype and shape per leaf) and the host arrays whose
+    bytes are stored, one per leaf."""
     leaves, treedef = jax.tree.flatten(tree)
-    metas, bufs = [], []
+    metas, arrays = [], []
     for leaf in leaves:
         arr = np.asarray(jax.device_get(leaf))
-        if arr.dtype == jnp.bfloat16:
-            metas.append({"dtype": "bfloat16", "shape": arr.shape})
-            bufs.append(arr.view(np.uint16).tobytes())
-        else:
-            metas.append({"dtype": str(arr.dtype), "shape": arr.shape})
-            bufs.append(arr.tobytes())
-    return {"treedef": str(treedef), "metas": metas}, bufs
+        dtype, raw = _buf_to_raw(arr)
+        metas.append({"dtype": dtype, "shape": arr.shape})
+        arrays.append(raw)
+    return {"treedef": str(treedef), "metas": metas}, arrays
 
 
 def save_checkpoint(path: str | Path, tree, extra: Optional[Dict] = None,
@@ -53,19 +106,9 @@ def save_checkpoint(path: str | Path, tree, extra: Optional[Dict] = None,
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     with obs.span("vc.writer.serialize", step=step):
-        header, bufs = _tree_to_payload(tree)
+        header, arrays = _tree_to_payload(tree)
     header["extra"] = extra or {}
-    with obs.span("vc.writer.write", step=step):
-        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(msgpack.packb(header, use_bin_type=True))
-                for b in bufs:
-                    f.write(msgpack.packb(b, use_bin_type=True))
-            os.replace(tmp, path)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+    _write_file(path, header, [(arr,) for arr in arrays], step=step)
 
 
 def load_checkpoint(path: str | Path, tree_like) -> Tuple[Any, Dict]:
@@ -102,19 +145,10 @@ def save_flat_checkpoint(path: str | Path, fp, extra: Optional[Dict] = None,
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     with obs.span("vc.writer.serialize", step=step):
-        buf_dtype, raw = _buf_to_bytes(np.asarray(jax.device_get(fp.buf)))
+        buf_dtype, raw = _buf_to_raw(np.asarray(jax.device_get(fp.buf)))
     header = {"flat": fp.spec.meta(), "buf_dtype": buf_dtype,
               "treedef": str(fp.spec.treedef), "extra": extra or {}}
-    with obs.span("vc.writer.write", step=step):
-        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(msgpack.packb(header, use_bin_type=True))
-                f.write(msgpack.packb(raw, use_bin_type=True))
-            os.replace(tmp, path)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+    _write_file(path, header, [(raw,)], step=step)
 
 
 def load_flat_checkpoint(path: str | Path, like) -> Tuple[Any, Dict]:
@@ -174,19 +208,21 @@ def _buf_from_bytes(raw: bytes, dtype_name: str) -> jnp.ndarray:
     return jnp.asarray(np.frombuffer(raw, np.dtype(dtype_name)))
 
 
-def _buf_to_bytes(arr: np.ndarray) -> Tuple[str, bytes]:
-    """Encode twin of _buf_from_bytes (bf16 rides as uint16 bits)."""
+def _buf_to_raw(arr: np.ndarray) -> Tuple[str, np.ndarray]:
+    """Encode twin of _buf_from_bytes: the dtype's name and the array whose
+    bytes are stored (bf16 rides as its uint16 bits, a view, not a copy)."""
     if arr.dtype == jnp.bfloat16:
-        return "bfloat16", arr.view(np.uint16).tobytes()
-    return str(arr.dtype), arr.tobytes()
+        return "bfloat16", arr.view(np.uint16)
+    return str(arr.dtype), arr
 
 
 # ---------------------------------------------------------------------------
 # one-pass TRAIN checkpoints: params + Adam m/v as THREE LANES OF ONE
 # CONTIGUOUS RECORD.  The whole training state (params, m, v, step) is
-# written with a single buffer write and restored atomically — the resume
-# path after preemption (core/simulator.py::run_preemptible_training) is
-# one read, zero leaf walks.
+# written as one bin object, lane after lane, and restored atomically —
+# the resume path after preemption
+# (core/simulator.py::run_preemptible_training) is one read, zero leaf
+# walks.
 # ---------------------------------------------------------------------------
 
 def save_train_checkpoint(path: str | Path, fp, opt,
@@ -200,25 +236,15 @@ def save_train_checkpoint(path: str | Path, fp, opt,
     if fp.spec.padded != opt.spec.padded or fp.spec.shapes != opt.spec.shapes:
         raise ValueError("params and optimizer state do not share a layout")
     with obs.span("vc.writer.serialize", step=step):
-        p_dtype, p_raw = _buf_to_bytes(np.asarray(jax.device_get(fp.buf)))
-        m_raw = np.asarray(jax.device_get(opt.m), np.float32).tobytes()
-        v_raw = np.asarray(jax.device_get(opt.v), np.float32).tobytes()
+        p_dtype, p_raw = _buf_to_raw(np.asarray(jax.device_get(fp.buf)))
+        m_raw = np.asarray(jax.device_get(opt.m), np.float32)
+        v_raw = np.asarray(jax.device_get(opt.v), np.float32)
         header = {"kind": "flat-train", "flat": fp.spec.meta(),
                   "buf_dtype": p_dtype,
-                  "lane_bytes": [len(p_raw), len(m_raw), len(v_raw)],
+                  "lane_bytes": [p_raw.nbytes, m_raw.nbytes, v_raw.nbytes],
                   "step": int(jax.device_get(opt.step)),
                   "treedef": str(fp.spec.treedef), "extra": extra or {}}
-        record = b"".join((p_raw, m_raw, v_raw))  # ONE contiguous record
-    with obs.span("vc.writer.write", step=step):
-        fd, tmp = tempfile.mkstemp(dir=path.parent, suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(msgpack.packb(header, use_bin_type=True))
-                f.write(msgpack.packb(record, use_bin_type=True))
-            os.replace(tmp, path)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+    _write_file(path, header, [(p_raw, m_raw, v_raw)], step=step)
 
 
 def load_train_checkpoint(path: str | Path, like) -> Tuple[Any, Any, Dict]:

@@ -1,11 +1,13 @@
 """Checkpoint durability: bit-exact round trip (incl. bf16), retention,
-kill/restore resume semantics, and the one-pass (params + m + v) train
-record — including a real-SIGKILL atomicity test (slow)."""
+kill/restore resume semantics, the one-pass (params + m + v) train
+record — including a real-SIGKILL atomicity test (slow) — and the file
+format's bytes, written with no payload-sized copy."""
 import os
 import signal
 import subprocess
 import sys
 import time
+import tracemalloc
 from pathlib import Path
 
 import jax
@@ -146,6 +148,164 @@ def test_manager_train_resume(tmp_path):
     assert step == 5 and extra["round"] == 5
     np.testing.assert_array_equal(np.asarray(fp.buf), np.asarray(fp2.buf))
     np.testing.assert_array_equal(np.asarray(fos.v), np.asarray(fos2.v))
+
+
+# ---------------------------------------------------------------------------
+# the file format: payloads framed by hand must give the bytes msgpack
+# gives (the loaders, and every checkpoint already on disk, rely on it),
+# in every bin size class, with no payload-sized copy on the writer.
+# ---------------------------------------------------------------------------
+
+def _raw(arr):
+    arr = np.asarray(arr)
+    if arr.dtype == jnp.bfloat16:
+        return "bfloat16", arr.view(np.uint16).tobytes()
+    return str(arr.dtype), arr.tobytes()
+
+
+def _packed(*objs) -> bytes:
+    return b"".join(msgpack.packb(o, use_bin_type=True) for o in objs)
+
+
+def _oracle_bytes(kind, state, extra) -> bytes:
+    """The file each saver must write, framed the plain way: ``tobytes``
+    copies, each object through ``msgpack.packb``."""
+    if kind == "tree":
+        leaves, treedef = jax.tree.flatten(state)
+        raws = [_raw(leaf) for leaf in leaves]
+        header = {"treedef": str(treedef),
+                  "metas": [{"dtype": d, "shape": np.shape(leaf)}
+                            for (d, _), leaf in zip(raws, leaves)],
+                  "extra": extra}
+        return _packed(header, *(r for _, r in raws))
+    fp = state if kind == "flat" else state[0]
+    dtype, p = _raw(fp.buf)
+    if kind == "flat":
+        return _packed({"flat": fp.spec.meta(), "buf_dtype": dtype,
+                        "treedef": str(fp.spec.treedef), "extra": extra}, p)
+    opt = state[1]
+    m = np.asarray(opt.m, np.float32).tobytes()
+    v = np.asarray(opt.v, np.float32).tobytes()
+    return _packed({"kind": "flat-train", "flat": fp.spec.meta(),
+                    "buf_dtype": dtype, "lane_bytes": [len(p), len(m), len(v)],
+                    "step": int(opt.step), "treedef": str(fp.spec.treedef),
+                    "extra": extra}, p + m + v)
+
+
+def _format_state(kind, n, dtype):
+    """A tree, a FlatParams of ``n`` elements of ``dtype`` (unpadded), or
+    such a FlatParams with Adam lanes, for ``kind``'s saver."""
+    ks = jax.random.split(jax.random.PRNGKey(n), 4)
+    if kind == "tree":           # payloads of every bin size class's edges
+        return {"f32": jax.random.normal(ks[0], (3, 5), jnp.float32),
+                "bf16": jax.random.normal(ks[1], (7,), jnp.bfloat16),
+                "i32": jnp.arange(6, dtype=jnp.int32).reshape(2, 3),
+                "scalar": (jnp.float32(2.5), jnp.bfloat16(-1.5),
+                           jnp.int32(-7)),
+                "empty": (jnp.zeros((0,), jnp.float32),
+                          jnp.zeros((0,), jnp.bfloat16)),
+                "edges": [jax.random.randint(ks[2], (b,), 0, 255, jnp.int32
+                                             ).astype(jnp.uint8)
+                          for b in (255, 256, 65535)]
+                + [jnp.arange(65536 // 4, dtype=jnp.int32)]}
+    fp = F.flatten({"w": jax.random.normal(ks[0], (n,))}, dtype=dtype,
+                   pad_to=1)
+    if kind == "flat":
+        return fp
+    return fp, F.FlatOptState(
+        m=jax.random.normal(ks[1], (n,), jnp.float32),
+        v=jax.random.uniform(ks[2], (n,), jnp.float32),
+        step=jnp.asarray(11, jnp.int32), spec=fp.spec)
+
+
+_SAVERS = {"tree": lambda path, st, e: save_checkpoint(path, st, e),
+           "flat": lambda path, st, e: save_flat_checkpoint(path, st, e),
+           "train": lambda path, st, e: save_train_checkpoint(path, *st, e)}
+
+# (kind, elements, dtype): payload bytes on both sides of each bin size
+# class's edge (bin 8 up to 255 bytes, bin 16 up to 65,535, then bin 32);
+# a train record is 2 + 4 + 4 = 10 bytes an element with bf16 params,
+# 12 with float32 params.
+_FORMAT_CASES = [
+    ("tree", 0, None),
+    ("flat", 63, jnp.float32), ("flat", 128, jnp.bfloat16),
+    ("flat", 16383, jnp.float32), ("flat", 16384, jnp.float32),
+    ("train", 25, jnp.bfloat16), ("train", 26, jnp.bfloat16),
+    ("train", 6553, jnp.bfloat16), ("train", 5462, jnp.float32),
+]
+
+
+@pytest.mark.parametrize(
+    "kind,n,dtype", _FORMAT_CASES,
+    ids=[f"{k}-{n}-{jnp.dtype(d).name if d else 'leaves'}"
+         for k, n, d in _FORMAT_CASES])
+def test_saved_file_bytes_match_msgpack_framing(tmp_path, kind, n, dtype):
+    state = _format_state(kind, n, dtype)
+    extra = {"round": 3, "tag": "x"}
+    path = tmp_path / "c.msgpack"
+    _SAVERS[kind](path, state, extra)
+    assert path.read_bytes() == _oracle_bytes(kind, state, extra)
+    assert not list(tmp_path.glob("*.tmp"))
+
+    if kind == "tree":
+        out, got_extra = load_checkpoint(path, state)
+        want, have = jax.tree.leaves(state), jax.tree.leaves(out)
+    elif kind == "flat":
+        out, got_extra = load_flat_checkpoint(path, state)
+        want, have = [state.buf], [out.buf]
+    else:
+        fp, opt, got_extra = load_train_checkpoint(path, state[0])
+        assert int(opt.step) == 11
+        want = [state[0].buf, state[1].m, state[1].v]
+        have = [fp.buf, opt.m, opt.v]
+    assert got_extra == extra
+    for a, b in zip(want, have):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def _host(x):
+    return jax.tree.map(np.asarray, x)
+
+
+@pytest.mark.parametrize("kind", ["tree", "train"])
+def test_save_makes_no_payload_sized_copy(tmp_path, kind):
+    """The writer saves the host arrays ``CheckpointManager._to_host``
+    hands it; tracing allocations (numpy's included), its peak over the
+    save stays under 1/16 of the ~64 MB payload."""
+    rng = np.random.default_rng(0)
+    if kind == "tree":
+        state = {"w": rng.standard_normal((2048, 4096), np.float32),
+                 "e": rng.standard_normal((1024, 4096), np.float32),
+                 "b": rng.standard_normal((2048, 4096), np.float32
+                                          ).astype(jnp.bfloat16)}
+        payload = sum(a.nbytes for a in state.values())
+    else:
+        fp = F.flatten({"w": rng.standard_normal((2048, 2731), np.float32)})
+        state = _host((fp, F.FlatOptState(m=fp.buf, v=fp.buf,
+                                          step=jnp.asarray(1, jnp.int32),
+                                          spec=fp.spec)))
+        payload = 3 * state[0].buf.nbytes
+    assert payload >= 60 << 20
+    tracemalloc.start()
+    try:
+        _SAVERS[kind](tmp_path / "c.msgpack", state, None)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < payload // 16, (peak, payload)
+    assert (tmp_path / "c.msgpack").stat().st_size > payload
+
+
+def test_payload_of_4gib_raises_like_msgpack_and_leaves_no_file(tmp_path):
+    """A payload msgpack cannot frame (2**32 bytes or more) raises
+    msgpack's ValueError before a byte of it is copied, and the temp file
+    goes."""
+    huge = np.lib.stride_tricks.as_strided(np.zeros(1, np.uint8),
+                                           shape=(1 << 32,), strides=(0,))
+    with pytest.raises(ValueError, match="too large"):
+        save_checkpoint(tmp_path / "c.msgpack", {"huge": huge})
+    assert list(tmp_path.iterdir()) == []
 
 
 # ---------------------------------------------------------------------------
