@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
-The reference (``bench/models``) follows the run's first ``check_rounds``
-rounds from the same seeded weights and the same tokens, in float32.  The
-numbers compared, each against the cell's limit in ``bench/limits/``:
+The reference (``bench/vc_ref.py`` round the cell's model, a module of
+``bench/models``) follows the run's first ``check_rounds`` rounds from the
+same seeded weights and the same tokens, in float32.  The numbers
+compared, each against the cell's limit in ``bench/limits/``:
 
 - ``loss_gap``: the widest gap, in nats, between a round's loss as the
   trainer returns it and the reference's, over the rounds followed;
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import traffic as TR
-from bench.models import dense_lm as M
+from bench import vc_ref as V
 
 EXCLUDE_BELOW = 1e-3
 
@@ -53,17 +54,19 @@ def leaf_norms(a, b=None) -> List[float]:
             zip(jax.tree.leaves(a), jax.tree.leaves(b))]
 
 
-def follow(dm: M.Dims, t: TR.Traffic, seed: int, weights0, *, cdt=None,
+def follow(model, dm, t: TR.Traffic, seed: int, weights0, *, cdt=None,
            half_batch: bool = False, alter_token: bool = False,
            no_exchange: bool = False, devices: Sequence = ()) -> Readout:
     """The reference's (or a planted fault's) first ``t.check_rounds``
-    rounds from ``weights0``.  Island p runs on ``devices[p % len]``."""
-    ref = M.Reference(dm, t.lr, cdt=cdt,
+    rounds from ``weights0``, with the loss of ``model`` (the cell's
+    module of ``bench/models``) at sizes ``dm``.  Island p runs on
+    ``devices[p % len]``."""
+    ref = V.Reference(model, dm, t.lr, cdt=cdt,
                       rows=t.batch // 2 if half_batch else None)
-    devices = list(devices) or [M._device_of(weights0)]
+    devices = list(devices) or [V._device_of(weights0)]
     dev = [devices[p % len(devices)] for p in range(t.islands)]
     server = weights0
-    opts = [M.adam_init(jax.device_put(server, d)) for d in dev]
+    opts = [V.adam_init(jax.device_put(server, d)) for d in dev]
     out = Readout(losses=[], norms={})
     for i in range(t.check_rounds):
         rnd = t.first_round + i
@@ -85,11 +88,11 @@ def follow(dm: M.Dims, t: TR.Traffic, seed: int, weights0, *, cdt=None,
         steps = [[float(x) for x in ls] for ls in losses]
         out.steps.extend(steps)
         out.losses.append(float(np.mean(steps)))
-        w, w_s = M.eq2_weights(t.islands, alpha, alive)
+        w, w_s = V.eq2_weights(t.islands, alpha, alive)
         if no_exchange:
             server = _eq2_no_exchange(server, isl, w, w_s)
         else:
-            server = M.eq2(server, isl, w, w_s)
+            server = V.eq2(server, isl, w, w_s)
         del isl
         if (rnd + 1) % t.ckpt_every == 0:
             out.norms[rnd + 1] = leaf_norms(server, weights0)
@@ -110,7 +113,7 @@ def _eq2_no_exchange(server, islands, w, w_s):
     leaves merges only that island, as if the chips never exchanged their
     segments."""
     leaves, tdef = jax.tree.flatten(server)
-    isl = [jax.tree.leaves(jax.device_put(x, M._device_of(server)))
+    isl = [jax.tree.leaves(jax.device_put(x, V._device_of(server)))
            for x in islands]
     n = len(islands)
     out = []

@@ -45,17 +45,18 @@ VARIANTS = {"fp8": {"cdt": "float8"},
 
 def readings_for(cell, seed: int, variants, devices, out=print) -> None:
     from bench import check as C
-    from bench.models import dense_lm as M
+    from bench.vc_ref import seed_words
+    M = cell.model
     dm = M.dims(cell.conf)
     init = M.make_init(dm)
-    w0 = init(M.seed_words(seed))
+    w0 = init(seed_words(seed))
     used = devices[:cell.traffic.islands]
     t0 = time.perf_counter()
-    ref = C.follow(dm, cell.traffic, seed, w0, devices=used)
+    ref = C.follow(M, dm, cell.traffic, seed, w0, devices=used)
     ref_s = time.perf_counter() - t0
     for v in variants:
         t0 = time.perf_counter()
-        got = C.follow(dm, cell.traffic, seed, w0, devices=used,
+        got = C.follow(M, dm, cell.traffic, seed, w0, devices=used,
                        **VARIANTS[v])
         out(json.dumps({"cell": cell.name, "seed": seed, "variant": v,
                         "readings": C.readings(got, ref),

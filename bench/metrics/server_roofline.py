@@ -12,6 +12,6 @@ def read(ctx):
         return None
     t = ctx.cell.traffic
     bw = peaks.lookup(ctx.device_kind)["hbm_bytes_per_s"]
-    need = work.server_bytes_per_chip(work.params(ctx.dm), t.islands,
-                                      ctx.chips) / bw
+    need = work.server_bytes_per_chip(ctx.model.params(ctx.dm),
+                                      t.islands, ctx.chips) / bw
     return 100.0 * need / (ms / 1e3)

@@ -1,4 +1,4 @@
-"""Plain float32 reference of a dense decoder-only LM and of the VC round.
+"""Plain float32 reference of a dense decoder-only LM (model ``dense_lm``).
 
 Independent of the program under test: nothing here imports it.  The
 parameters are laid out as the program stores them, so that one tree can be
@@ -10,10 +10,9 @@ handed to both (a dict per block, every leaf stacked over the layers):
 The model is pre-norm: RMSNorm or LayerNorm, rotate-half rotary embedding
 over the first ``rot`` dimensions of each head, causal softmax attention
 with grouped KV heads, a SiLU-gated MLP and an untied output head; the loss
-is the mean next-token cross entropy over the real vocabulary.  Training is
-Adam (b1 0.9, b2 0.999, eps 1e-8, no weight decay) at the traffic's
-learning rate (``lr_schedule``) after clipping the global gradient norm to
-1; the server merges islands by the paper's Eq. 2.
+is the mean next-token cross entropy over the real vocabulary.  The VC
+round around it (Adam, Eq. 2) is ``bench/vc_ref.py``; what a model module
+provides is set out in ``bench/models/__init__.py``.
 
 Every matmul runs at ``Precision.HIGHEST``.  ``cdt`` rounds the values that
 the program keeps in its compute dtype (the residual stream, matmul
@@ -28,15 +27,12 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Callable, Optional, Sequence
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-ADAM = dict(b1=0.9, b2=0.999, eps=1e-8)
-CLIP_NORM = 1.0
 BLOCK_ROWS = 4      # rows of a batch that go through the model at a time
 
 
@@ -90,6 +86,14 @@ def flops_per_token(dm: Dims, seq: int) -> float:
     return 6.0 * dm.matmul_params + 6.0 * dm.layers * dm.heads * dm.hd * seq
 
 
+def params(dm: Dims) -> int:
+    """Parameters of the server: every leaf of the model."""
+    attn = dm.d * dm.hd * (2 * dm.heads + 2 * dm.kv_heads)
+    norms = dm.d * (2 if dm.layernorm else 1)
+    block = attn + 3 * dm.d * dm.ff + 2 * norms
+    return dm.layers * block + 2 * dm.vocab_padded * dm.d + norms
+
+
 # ---------------------------------------------------------------------------
 # weights
 # ---------------------------------------------------------------------------
@@ -139,12 +143,6 @@ def make_init(dm: Dims) -> Callable:
         key = jax.random.fold_in(jax.random.PRNGKey(words[0]), words[1])
         return init_weights(dm, key)
     return init
-
-
-def seed_words(seed: int) -> np.ndarray:
-    """Any non-negative seed as two 32-bit words (PRNGKey drops high bits)."""
-    return np.array([seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF],
-                    np.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -238,112 +236,3 @@ def nll_sum(params, tokens, dm: Dims, cdt=None, scale=1.0):
     logz = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
     return jnp.sum(logz - picked) * scale
-
-
-# ---------------------------------------------------------------------------
-# the VC round
-# ---------------------------------------------------------------------------
-
-def lr_schedule(spec) -> Callable:
-    """The learning rate at Adam step ``t`` (from 1) for a traffic's
-    ``lr``: a constant rate, or a linear warm-up to ``peak`` over
-    ``warmup_steps`` followed by a cosine decay to ``min_frac`` of it at
-    ``total_steps``."""
-    if not isinstance(spec, dict):
-        return lambda t: jnp.float32(spec)
-    peak, warm = spec["peak"], spec["warmup_steps"]
-    span = max(spec["total_steps"] - warm, 1)
-    floor = spec["min_frac"]
-
-    def lr(t):
-        t = t.astype(jnp.float32)
-        done = jnp.clip((t - warm) / span, 0.0, 1.0)
-        cos = floor + (1 - floor) * 0.5 * (1 + jnp.cos(math.pi * done))
-        return jnp.where(t < warm, peak * t / warm, peak * cos)
-    return lr
-
-
-class Reference:
-    """Adam islands and the Eq. 2 server, step by step, in float32, at the
-    learning rate ``lr`` (a traffic's ``lr``).
-
-    ``BLOCK_ROWS`` rows of a batch go through the model at a time, and
-    their gradients are summed, so that the reference fits beside nothing
-    else on one chip.  ``rows`` keeps only the first rows of each batch (a
-    planted fault: half the batch left out)."""
-
-    def __init__(self, dm: Dims, lr, *, cdt=None,
-                 rows: Optional[int] = None):
-        self.dm, self.rows = dm, rows
-        self._grad = jax.jit(jax.value_and_grad(
-            partial(nll_sum, dm=dm, cdt=cdt)))
-        self._add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
-        self._update = jax.jit(partial(_clip_adam, lr=lr_schedule(lr)))
-
-    def step(self, params, opt, tokens):
-        """One local step on ``tokens`` [b, s]: returns (params', opt',
-        loss, clipped gradient)."""
-        tokens = np.asarray(tokens)[: self.rows]
-        b, s = tokens.shape
-        dev = _device_of(params)
-        scale = jnp.float32(1.0 / (b * (s - 1)))
-        loss, grads = None, None
-        for r0 in range(0, b, BLOCK_ROWS):
-            blk = jax.device_put(tokens[r0: r0 + BLOCK_ROWS], dev)
-            part, g = self._grad(params, blk, scale=scale)
-            loss = part if loss is None else loss + part
-            grads = g if grads is None else self._add(grads, g)
-        params, opt, grads = self._update(params, opt, grads)
-        return params, opt, loss, grads
-
-
-def _device_of(tree):
-    return next(iter(jax.tree.leaves(tree)[0].devices()))
-
-
-def adam_init(params):
-    z = jax.tree.map(jnp.zeros_like, params)
-    return {"t": jnp.zeros((), jnp.int32), "m": z, "v": z}
-
-
-def _clip_adam(params, opt, grads, lr):
-    gn = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
-    scale = jnp.minimum(1.0, CLIP_NORM / jnp.maximum(gn, 1e-9))
-    grads = jax.tree.map(lambda g: g * scale, grads)
-    t = opt["t"] + 1
-    b1, b2 = ADAM["b1"], ADAM["b2"]
-    m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, opt["m"], grads)
-    v = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, opt["v"], grads)
-    c1 = 1 - b1 ** t.astype(jnp.float32)
-    c2 = 1 - b2 ** t.astype(jnp.float32)
-    rate = lr(t)
-    params = jax.tree.map(
-        lambda p, m, v: p - rate * (m / c1) / (jnp.sqrt(v / c2)
-                                               + ADAM["eps"]),
-        params, m, v)
-    return params, {"t": t, "m": m, "v": v}, grads
-
-
-def eq2_weights(n: int, alpha: float, survivors: Sequence[bool]):
-    """Eq. 2: island j (arrival order) weighs (1 - a) a^(n-1-j); dead
-    islands weigh 0 and the server takes the rest."""
-    w = [(1.0 - alpha) * alpha ** (n - 1 - j) * bool(survivors[j])
-         for j in range(n)]
-    return w, 1.0 - sum(w)
-
-
-@jax.jit
-def _eq2(server, islands, w, w_s):
-    def merge(s, *isl):
-        out = w_s * s
-        for j, x in enumerate(isl):
-            out = out + w[j] * x
-        return out
-    return jax.tree.map(merge, server, *islands)
-
-
-def eq2(server, islands, w, w_s):
-    dev = _device_of(server)
-    islands = [jax.device_put(x, dev) for x in islands]
-    return _eq2(server, islands, jnp.asarray(w, jnp.float32),
-                jnp.float32(w_s))

@@ -7,7 +7,10 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``: a configuration
 (``bench/configs/<config>.json``) under a traffic mix
 (``bench/workloads/<traffic>.json``), with the limits of its check in
-``bench/limits/<cell>.json``.  A run:
+``bench/limits/<cell>.json``.  The configuration's ``"model"`` names its
+reference and the program's side of it, ``bench/models/<model>.py`` and
+``bench/models/<model>_program.py`` (see ``bench/models/__init__.py``).
+A run:
 
 1. set-up: makes the weights from ``--seed`` on the device in one jitted
    call, writes them as the server's checkpoint at the traffic's first
@@ -42,6 +45,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Dict, List  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
@@ -49,6 +53,7 @@ ROOT = BENCH.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
+from bench import models  # noqa: E402
 from bench import traffic as TR  # noqa: E402
 
 CACHE_DIR = BENCH / ".jax_cache"
@@ -64,6 +69,8 @@ class Cell:
     name: str
     chips: int
     conf: dict
+    model: ModuleType       # the reference, bench/models/<model>.py
+    program: ModuleType     # the program's side, <model>_program.py
     traffic: TR.Traffic
     limits: Dict[str, float]
     end_to_end: List[dict]
@@ -79,6 +86,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     conf = json.loads((root / cfg["file"]).read_text())
+    model, program = models.load(conf)
     traffic = TR.load(root / "bench" / "workloads" / f"{w['traffic']}.json")
     limits = json.loads((root / "bench" / "limits" / f"{name}.json")
                         .read_text())["limits"]
@@ -88,7 +96,8 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
-    return Cell(name, w["chips"], conf, traffic, limits, e2e, per_layer)
+    return Cell(name, w["chips"], conf, model, program, traffic, limits,
+                e2e, per_layer)
 
 
 def percentile(values: List[float], q: float) -> float:
@@ -225,14 +234,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     import jax
     import numpy as np
     from bench import check as C
-    from bench.models import dense_lm as M
-    from bench.models.dense_lm_program import program_config
+    from bench.vc_ref import seed_words
     from repro.launch import train as T
     from repro.models.registry import build_model
 
     t = cell.traffic
+    M = cell.model
     dm = M.dims(cell.conf)
-    pcfg = program_config(cell.conf)
+    pcfg = cell.program.program_config(cell.conf)
     n_window = t.window_rounds(seconds)
     rounds = t.first_round + 1 + n_window
     # checkpoints the check reads: those of the rounds the reference follows
@@ -247,7 +256,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # -- set-up: the seeded weights become the server's checkpoint --------
     init = M.make_init(dm)
-    words = M.seed_words(seed)
+    words = seed_words(seed)
     weights = init(words)
     like = jax.eval_shape(lambda: build_model(pcfg).init(
         jax.random.PRNGKey(0)))
@@ -329,7 +338,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             s_k = restore_step(kept, step, like, work)
             prog.norms[step] = C.leaf_norms(s_k, weights0)
             del s_k
-    ref = C.follow(dm, t, seed, weights0, devices=used)
+    ref = C.follow(M, dm, t, seed, weights0, devices=used)
     numbers = C.readings(prog, ref, ckpt_bits)
     limits = dict(cell.limits)
     if len(ends) != 1 + n_window:        # round-ends the log did not show
@@ -345,9 +354,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         from bench import trace as TRC
         tr = TRC.load(trace_dir)
-        ctx = Context(cell=cell, dm=dm, trace=tr, window_s=window_s,
-                      rounds=n_window, tokens=tokens, chips=cell.chips,
-                      device_kind=dev.device_kind)
+        ctx = Context(cell=cell, model=M, dm=dm, trace=tr,
+                      window_s=window_s, rounds=n_window, tokens=tokens,
+                      chips=cell.chips, device_kind=dev.device_kind)
         metrics = {}
         for m in cell.per_layer:
             v = importlib.import_module(f"bench.metrics.{m['name']}"
@@ -379,7 +388,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 class Context:
     """What a per-layer metric reader is given."""
     cell: Cell
-    dm: object
+    model: ModuleType       # the cell's reference, for counts from sizes
+    dm: object              # its sizes, model.dims(cell.conf)
     trace: object
     window_s: float
     rounds: int

@@ -10,25 +10,30 @@ import os
 import subprocess
 import sys
 import time
+from collections import Counter
 from pathlib import Path
+from types import ModuleType
 
 import jax
 import pytest
 
 from bench import check as C
 from bench import control as K
-from bench import run, traffic
+from bench import models, peaks, run, traffic
 
 DATA = Path(__file__).resolve().parent / "data"
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
-def tiny_cell(traffic_file="tiny_traffic.json"):
+def tiny_cell(traffic_file="tiny_traffic.json", model=None):
     conf = json.loads((DATA / "tiny.json").read_text())
+    if model:
+        conf["model"] = model
     limits = json.loads((DATA / "tiny_limits.json").read_text())["limits"]
-    return run.Cell("tiny", 1, conf, traffic.load(DATA / traffic_file),
-                    limits, BENCH["end_to_end"],
+    return run.Cell("tiny", 1, conf, *models.load(conf),
+                    traffic.load(DATA / traffic_file), limits,
+                    BENCH["end_to_end"],
                     [m for m in BENCH["per_layer"] if "workloads" not in m])
 
 
@@ -72,6 +77,49 @@ def test_traced_run_reports_per_layer_metrics_it_can_read(tmp_path):
     # no TPU plane on the CPU: only the host spans' readers find something
     assert set(r["metrics"]) == {"ckpt_stall_ms", "batch_ms"}
     assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_a_model_added_as_new_modules_drives_the_whole_run(tmp_path,
+                                                           monkeypatch):
+    """A model module under a new name, registered as the files
+    ``bench/models/counted_lm.py`` and ``counted_lm_program.py`` would be,
+    drives a traced run to ``correct``: the harness calls that module's
+    interface, and nothing of ``dense_lm`` directly."""
+    from bench.models import dense_lm, dense_lm_program
+    calls = Counter()
+
+    def counted(fn):
+        def call(*a, **k):
+            calls[fn.__name__] += 1
+            return fn(*a, **k)
+        return call
+
+    def refused(*a, **k):
+        raise AssertionError("dense_lm called directly")
+
+    ref = ModuleType("bench.models.counted_lm")
+    prog = ModuleType("bench.models.counted_lm_program")
+    for name in models.REFERENCE:
+        real = getattr(dense_lm, name)
+        setattr(ref, name, counted(real) if callable(real) else real)
+        if callable(real):
+            monkeypatch.setattr(dense_lm, name, refused)
+    prog.program_config = counted(dense_lm_program.program_config)
+    monkeypatch.setattr(dense_lm_program, "program_config", refused)
+    monkeypatch.setitem(sys.modules, ref.__name__, ref)
+    monkeypatch.setitem(sys.modules, prog.__name__, prog)
+    # the CPU is in no peak table: read the chip's, so that mfu asks the
+    # model for its FLOPs (the shares read are not the CPU's, and unused)
+    chip = peaks.lookup("TPU v5 lite")
+    monkeypatch.setattr(peaks, "lookup", lambda kind: chip)
+
+    cell = tiny_cell(model="counted_lm")
+    assert (cell.model, cell.program) == (ref, prog)
+    r = run.run_cell(cell, 2 ** 31 + 17, 10, True, devices=jax.devices(),
+                     t_start=time.perf_counter(), work=tmp_path / "work")
+    assert r["correct"] is True, r["checks"]
+    assert set(calls) == {"dims", "make_init", "nll_sum", "flops_per_token",
+                          "params", "program_config"}, calls
 
 
 def _break_round(monkeypatch, how):

@@ -1,16 +1,17 @@
-"""The benchmark's yardstick on the CPU: discovery by name, configuration
-cuts, FLOP and byte counts, the peak table, the trace reduction on a
-recorded trace, and the refusal to run without a TPU."""
+"""The benchmark's yardstick on the CPU: discovery by name (cells, and
+each configuration's model), configuration cuts, FLOP and byte counts, the
+peak table, the trace reduction on a recorded trace, and the refusal to run
+without a TPU."""
 import json
 import os
 import subprocess
 import sys
 from pathlib import Path
+from types import ModuleType
 
 import pytest
 
-from bench import peaks, run, trace, traffic, work
-from bench.models import dense_lm as M
+from bench import models, peaks, run, trace, traffic, vc_ref, work
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
@@ -36,6 +37,11 @@ def conf(name):
     return json.loads((ROOT / c["file"]).read_text())
 
 
+def model(name):
+    """The reference module the configuration ``name`` names."""
+    return models.load(conf(name))[0]
+
+
 @pytest.mark.parametrize("name", sorted(PUBLISHED))
 def test_config_keeps_published_widths_and_cuts_only_depth_and_vocab(name):
     c, pub = conf(name), PUBLISHED[name]
@@ -58,6 +64,7 @@ def test_config_keeps_published_widths_and_cuts_only_depth_and_vocab(name):
                          + 2560 * 6288) + 6 * 2 * 32 * 80 * 1024),
 ])
 def test_flops_per_token_matches_hand_count(name, want):
+    M = model(name)
     assert M.flops_per_token(M.dims(conf(name)), 1024) == want
     assert want in (922288128, 1079623680)
 
@@ -65,8 +72,8 @@ def test_flops_per_token_matches_hand_count(name, want):
 @pytest.mark.parametrize("name,n", [("internlm2-1.8b", 173221888),
                                     ("stablelm-3b", 190817280)])
 def test_parameter_count_and_server_bytes(name, n):
-    dm = M.dims(conf(name))
-    assert work.params(dm) == n
+    M = model(name)
+    assert M.params(M.dims(conf(name))) == n
     assert work.server_bytes_per_chip(n, 1, 1) == 16 * n
     assert work.server_bytes_per_chip(n, 4, 4) == 10 * n
 
@@ -103,6 +110,36 @@ def test_unknown_cell_is_refused():
         run.load_cell("no-such-cell", ROOT)
 
 
+@pytest.mark.parametrize("name,registered,error,names", [
+    ("no_such_lm", None, FileNotFoundError, "bench/models/no_such_lm.py"),
+    ("half_lm", "full", FileNotFoundError,
+     "bench/models/half_lm_program.py"),
+    ("thin_lm", "without params", AttributeError, "['params']"),
+])
+def test_a_configuration_whose_model_has_no_module_is_refused(
+        tmp_path, monkeypatch, name, registered, error, names):
+    """``load_cell`` refuses, before any device is touched, a cell whose
+    configuration names a model with no module under ``bench/models``
+    (or one that lacks part of the interface), naming what is missing."""
+    if registered:
+        ref = ModuleType(f"bench.models.{name}")
+        for attr in models.REFERENCE:
+            setattr(ref, attr, None)
+        if registered == "without params":
+            del ref.params
+        monkeypatch.setitem(sys.modules, ref.__name__, ref)
+    raw = json.loads((DATA / "tiny.json").read_text())
+    (tmp_path / "tiny.json").write_text(json.dumps({**raw, "model": name}))
+    bench = {"configs": [{"name": "tiny", "file": "tiny.json"}],
+             "workloads": [{"name": "tiny.local8", "config": "tiny",
+                            "traffic": "local8", "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(error) as e:
+        run.load_cell("tiny.local8", tmp_path)
+    assert names in str(e.value)
+
+
 def test_window_rounds_scale_with_seconds():
     t = traffic.load(ROOT / "bench" / "workloads" / "local8.json")
     assert t.window_rounds(20) == 8
@@ -119,7 +156,7 @@ def test_window_rounds_scale_with_seconds():
 def test_reference_rate_follows_the_trainers_schedule(spec):
     import jax.numpy as jnp
     import numpy as np
-    ref = M.lr_schedule(spec)
+    ref = vc_ref.lr_schedule(spec)
     prog = run.program_lr(spec)
     for t in (1, 2, 3, 4, 5, 8, 19, 20, 21, 100, 9999, 10000, 20000):
         step = jnp.asarray(t, jnp.int32)
@@ -170,7 +207,7 @@ def test_tokens_are_seeded_and_large_seeds_work():
     c = traffic.tokens(t, 256, 2 ** 31 + 5, 1, 0, 1)
     assert a.shape == (t.batch, t.seq) and (a == b).all()
     assert not (a == c).all() and a.max() < 256
-    assert list(M.seed_words(2 ** 33 + 7)) == [7, 2]
+    assert list(vc_ref.seed_words(2 ** 33 + 7)) == [7, 2]
 
 
 def test_percentile_is_linear_between_ranks():
@@ -228,7 +265,9 @@ def test_reduction_of_a_recorded_chip_trace():
     assert len(loop) == 1 and loop[0].dur == pytest.approx(1.3416964)
     assert tr.busy_s(0) == pytest.approx(1.3765680, rel=1e-6)
     assert tr.top_ops(1)[0][0] == "bitcast_dynamic-update-slice_fusion.23"
-    ctx = SimpleNamespace(trace=tr, dm=M.dims(conf("internlm2-1.8b")),
+    M = model("internlm2-1.8b")
+    ctx = SimpleNamespace(trace=tr, model=M,
+                          dm=M.dims(conf("internlm2-1.8b")),
                           chips=1, rounds=1, window_s=1.4067599,
                           device_kind="TPU v5 lite",
                           cell=SimpleNamespace(traffic=SimpleNamespace(

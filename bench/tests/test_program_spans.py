@@ -14,7 +14,6 @@ import pytest
 from bench import program_trace as PT
 from bench import run, trace
 from bench.metrics import ckpt_idle_ms, ckpt_write_ms, window_compiles
-from bench.models.dense_lm_program import program_config
 from bench.tests.test_bench_check import tiny_cell
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -43,7 +42,8 @@ def inside(child, parent):
 
 def test_a_traced_train_opens_every_span_with_its_args(tmp_path):
     from repro.launch import train as T
-    cfg = program_config(json.loads((DATA / "tiny.json").read_text()))
+    cell = tiny_cell()
+    cfg = cell.program.program_config(cell.conf)
     ckpt = tmp_path / "ckpt"
     with jax.profiler.trace(str(tmp_path / "trace")):
         T.train(cfg, ckpt_dir=str(ckpt), islands=1, rounds=3, local_steps=1,
